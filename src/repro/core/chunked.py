@@ -56,9 +56,9 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .bucketing import make_edges, threshold_from_hist
 from .postprocess import (
     profit_edges,

@@ -81,10 +81,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import ckpt
-from ..compat import shard_map
 from ..obs import NULL_TRACER
 from .bucketing import make_edges, threshold_from_hist
 from .faults import policy_from_cfg, resilient_source

@@ -48,9 +48,9 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .bucketing import (
     bucket_histogram,
     exact_threshold,
@@ -67,7 +67,8 @@ from .scd import candidates_general
 from .sparse_scd import candidates_sparse, select_sparse
 from .types import DenseKP, SolverConfig, SparseKP
 
-__all__ = ["SolveResult", "solve", "solve_sharded", "dual_objective"]
+__all__ = ["SolveResult", "solve", "solve_fn", "solve_sharded",
+           "dual_objective"]
 
 
 class SolveResult(NamedTuple):
@@ -560,14 +561,22 @@ def solve(kp, cfg: SolverConfig = SolverConfig(), q: int = 1, lam0=None):
     The instance itself stays device-resident — for out-of-core n see
     ``repro.core.chunked.solve_streaming``.
     """
-    _validate_cfg(cfg)
     k = kp.budgets.shape[0]
     if lam0 is None:
         lam0 = jnp.ones((k,), cfg.dtype)
-    fn = jax.jit(
-        functools.partial(_solve_entry, q=q, cfg=cfg, axis=None),
-    )
-    return fn(kp, lam0)
+    return solve_fn(cfg, q)(kp, lam0)
+
+
+def solve_fn(cfg: SolverConfig = SolverConfig(), q: int = 1):
+    """Build the jitted single-device entry: (kp, lam0) -> SolveResult.
+
+    :func:`solve` calls the returned function; AOT consumers call
+    ``.lower()`` on it, so the program they inspect (memory analysis,
+    whether the Pallas kernels compiled natively) is exactly the one
+    users run. Validates cfg like :func:`solve`.
+    """
+    _validate_cfg(cfg)
+    return jax.jit(functools.partial(_solve_entry, q=q, cfg=cfg, axis=None))
 
 
 def solve_sharded(kp, mesh, cfg: SolverConfig = SolverConfig(), q: int = 1,

@@ -178,8 +178,9 @@ class SolverConfig:
     # reactivates every chunk for one full pass and re-anchors. Smaller
     # values retire chunks earlier but survive larger downward swings.
     screening_floor: float = 0.5
-    # Use the Pallas kernels for the sparse map + histogram (TPU target;
-    # interpret-mode on CPU — slow, used for integration testing).
+    # Use the Pallas kernels for the sparse map + histogram: compiled on a
+    # TPU, interpreted on the CPU (slow; integration tests only), refused
+    # on any other backend (kernels/_util.resolve_interpret).
     use_kernels: bool = False
     # Apply the §5.4 feasibility projection to the returned primal.
     postprocess: bool = True

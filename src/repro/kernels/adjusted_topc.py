@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import pad_rows
+from ._util import pad_rows, resolve_interpret
 
 
 def _topq_mask(ap, q):
@@ -56,8 +56,7 @@ def _kernel(p_ref, b_ref, lam_ref, x_ref, v_ref, *, q):
 def adjusted_topc(p, b, lam, q, tile_n=512, interpret=None):
     """p, b: (n, K); lam: (K,). Returns (x bool (n,K), v (n,K))."""
     n, k = p.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     # Ragged n: padded rows have ap = 0, never strictly positive, so the
     # top-q mask is all-False there; slice the outputs back.

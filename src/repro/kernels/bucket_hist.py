@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import pad_rows
+from ._util import pad_rows, resolve_interpret
 
 
 def hist_block(v1, v2, edges):
@@ -38,6 +38,9 @@ def hist_block(v1, v2, edges):
     idx = gt.sum(axis=-1).astype(jnp.int32)               # (tile_n, K)
     buckets = jax.lax.broadcasted_iota(jnp.int32, (tile_n, k, nb), 2)
     onehot = (buckets == idx[:, :, None]).astype(jnp.float32)
+    # DEFAULT precision: chip_smoke.py phase (f) checks on the chip that
+    # this K-batched contraction keeps f32 masses, unlike the unbatched
+    # one-hot matmul of scd_fused's finalize, which needs HIGHEST.
     return jnp.einsum("nkb,nk->kb", onehot, v2.astype(jnp.float32))
 
 
@@ -56,8 +59,7 @@ def bucket_hist(v1, v2, edges, tile_n=512, interpret=None):
     """v1, v2: (n, K); edges: (K, E) ascending. Returns (K, E+1) f32."""
     n, k = v1.shape
     e = edges.shape[-1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     # Ragged n: padded rows carry v2 = 0, i.e. zero mass in every bucket.
     pad = -n % tile_n

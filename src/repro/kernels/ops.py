@@ -1,9 +1,10 @@
 """Jitted public wrappers for the kernel layer.
 
-On TPU these call the Pallas kernels compiled natively; on CPU (this
-container) they run the same kernel bodies under ``interpret=True``, which
-traces the kernel through XLA so correctness (incl. the grid accumulation
-pattern) is exercised end to end. ``use_pallas=False`` falls back to the
+On a TPU these call the Pallas kernels compiled natively; on the CPU they
+run the same kernel bodies under ``interpret=True``, which traces the
+kernel through XLA so correctness (incl. the grid accumulation pattern) is
+exercised end to end. Any other backend raises
+(``_util.resolve_interpret``). ``use_pallas=False`` falls back to the
 pure-jnp oracle — the solver uses that switch to A/B the kernel path.
 """
 from __future__ import annotations

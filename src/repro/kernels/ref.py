@@ -11,6 +11,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# The one-hot histogram contractions are MXU matmuls on a TPU, where
+# DEFAULT precision rounds f32 operands to bf16; an oracle must not.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def adjusted_topc_ref(p, b, lam, q):
     """Fused DD/SCD map body, sparse GKP (one item per knapsack).
@@ -105,8 +109,10 @@ def scd_finalize_ref(p, b, lam, pedges, q, with_hist=True,
     e = pedges.shape[-1]
     idx = jnp.searchsorted(pedges, pt, side="left")          # (n,)
     onehot = jax.nn.one_hot(idx, e + 1, dtype=jnp.float32)   # (n, E+1)
-    ch = jnp.einsum("nb,nk->kb", onehot, cons.astype(jnp.float32))
-    gh = jnp.einsum("nb,n->b", onehot, gain.astype(jnp.float32))
+    ch = jnp.einsum("nb,nk->kb", onehot, cons.astype(jnp.float32),
+                    precision=_EXACT)
+    gh = jnp.einsum("nb,n->b", onehot, gain.astype(jnp.float32),
+                    precision=_EXACT)
     if cons_hist_init is not None:
         ch = ch + cons_hist_init
     if gain_hist_init is not None:
@@ -124,4 +130,4 @@ def bucket_hist_ref(v1, v2, edges):
     e = edges.shape[-1]
     idx = jax.vmap(jnp.searchsorted, in_axes=(0, 1))(edges, v1)   # (K, n)
     onehot = jax.nn.one_hot(idx, e + 1, dtype=v2.dtype)           # (K, n, E+1)
-    return jnp.einsum("kne,nk->ke", onehot, v2)
+    return jnp.einsum("kne,nk->ke", onehot, v2, precision=_EXACT)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import pad_rows
+from ._util import pad_rows, resolve_interpret
 
 
 def _order_stats(ap, q):
@@ -72,8 +72,7 @@ def _kernel(p_ref, b_ref, lam_ref, v1_ref, v2_ref, *, q):
 def scd_candidates(p, b, lam, q, tile_n=512, interpret=None):
     """p, b: (n, K); lam: (K,). Returns (v1, v2): (n, K) Alg 5 candidates."""
     n, k = p.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     # Ragged n: pad with (p=0, b=0) rows — invalid candidates (v1=-1,
     # v2=0) by construction — and slice the outputs back.

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import pad_rows
+from ._util import pad_rows, resolve_interpret
 from .adjusted_topc import _topq_mask
 from .bucket_hist import hist_block
 from .scd_candidates import candidates_block
@@ -78,8 +78,7 @@ def scd_fused_hist(p, b, lam, edges, q, tile_n=512, interpret=None,
     """
     n, k = p.shape
     e = edges.shape[-1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     pad = -n % tile_n
     p = pad_rows(p, pad)
@@ -183,7 +182,10 @@ def _finalize_kernel(p_ref, b_ref, lam_ref, *refs, q, with_hist):
     idx = jnp.sum(pt > pedges_ref[...], axis=1).astype(jnp.int32)  # (tile,)
     buckets = jax.lax.broadcasted_iota(jnp.int32, (tile_n, e + 1), 1)
     onehot = (buckets == idx[:, None]).astype(jnp.float32)
-    ch_ref[...] += jnp.einsum("nb,nk->kb", onehot, cons.astype(jnp.float32))
+    # HIGHEST: at DEFAULT, Mosaic feeds f32 operands to the MXU as bf16,
+    # rounding every consumption to ~3 significant digits (v5e).
+    ch_ref[...] += jnp.einsum("nb,nk->kb", onehot, cons.astype(jnp.float32),
+                              precision=jax.lax.Precision.HIGHEST)
     gh_ref[...] += jnp.sum(onehot * gain.astype(jnp.float32), axis=0,
                            keepdims=True)
 
@@ -220,8 +222,7 @@ def scd_finalize_hist(p, b, lam, pedges, q, tile_n=512, interpret=None,
     and never touch the lo/hi range.
     """
     n, k = p.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     pad = -n % tile_n
     p = pad_rows(p, pad)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import pad_rows
+from ._util import pad_rows, resolve_interpret
 
 
 def bound_block(p, b):
@@ -55,8 +55,7 @@ def screen_bound(p, b, tile_n=512, interpret=None):
     histogram kernels, no tile-order contract is needed.
     """
     n, k = p.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     tile_n = min(tile_n, n)
     # Ragged n: padded rows carry b = 0, i.e. masked to -inf.
     pad = -n % tile_n

@@ -37,10 +37,9 @@ import traceback
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 from repro.configs import registry
 from repro.configs.paper_kp import WORKLOADS
 from repro.launch.mesh import make_production_mesh
@@ -169,14 +168,12 @@ def lower_cell(arch: str, shape: str, multi_pod: bool, probe: bool = True,
               "mesh": "2x16x16" if multi_pod else "16x16", "status": "ok",
               "fsdp_mode": cfg.fsdp_mode, "router": cfg.moe.router or None,
               "global_batch": cell.global_batch}
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sharding.set_rules(rules)
         try:
             pshape = jax.eval_shape(
                 lambda k: M.init(cfg, k), jax.ShapeDtypeStruct((2,), jnp.uint32))
             pspecs, ospecs, bspecs = M.shardings(cfg, cell, multi_pod)
-            pspecs, ospecs, bspecs = compat.as_shardings(
-                mesh, (pspecs, ospecs, bspecs))
             inputs = _abstract(M.input_specs(cfg, cell))
 
             if cell.kind == "train":
@@ -263,7 +260,7 @@ def _probe_block(cfg, cell, mesh, multi_pod):
             P(rules["batch"], rules["seq"], None), x_sds.shape)
         lowered = jax.jit(
             probe_fn,
-            in_shardings=compat.as_shardings(mesh, (pspecs, x_spec)),
+            in_shardings=(pspecs, x_spec),
         ).lower(pshape, x_sds)
     else:
         # decode probe: one period of block_decode
@@ -296,8 +293,7 @@ def _probe_block(cfg, cell, mesh, multi_pod):
         x_spec = M.sanitize(P(rules["batch"], None, None), x_sds.shape)
         lowered = jax.jit(
             probe_fn,
-            in_shardings=compat.as_shardings(
-                mesh, (pspecs, cspecs, x_spec, P())),
+            in_shardings=(pspecs, cspecs, x_spec, P()),
         ).lower(pshape, cshape, x_sds, jax.ShapeDtypeStruct((), jnp.int32))
 
     compiled = lowered.compile()

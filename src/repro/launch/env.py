@@ -12,11 +12,14 @@ starts. This module owns that assembly:
   surgery on an ``XLA_FLAGS`` value: replace one ``--flag=value`` token
   while preserving every other flag the caller (or CI) already set.
 * :func:`worker_env` — the subprocess environment for one worker: base
-  env (default ``os.environ``) with the platform pinned and the host
-  platform forced to ``devices`` virtual devices. This is how the
-  supervisor respawns a takeover on a *degraded* device count — the
-  child's mesh is smaller, the checkpoint's virtual slot count is not,
-  and PR 4's elastic resume keeps the result bitwise.
+  env (default ``os.environ``), the platform inherited unless the caller
+  pins one, and — on the CPU platform only — the host platform forced to
+  ``devices`` virtual devices. This is how the supervisor respawns a
+  CPU takeover on a *degraded* device count — the child's mesh is
+  smaller, the checkpoint's virtual slot count is not, and elastic
+  resume keeps the result bitwise. A TPU worker sees the chips it has.
+* :func:`enable_compile_cache` — the persistent compilation cache every
+  entry point turns on first.
 * :func:`set_host_device_count` / :func:`set_platform` /
   :func:`enable_x64` — in-process setters for the same knobs, guarded
   against the classic footgun of calling them after JAX has already
@@ -26,14 +29,18 @@ starts. This module owns that assembly:
 from __future__ import annotations
 
 import os
+import pathlib
 import sys
 from typing import Mapping, Optional
 
 __all__ = ["DEVICE_COUNT_FLAG", "merged_xla_flags", "host_device_flags",
-           "worker_env", "set_host_device_count", "set_platform",
-           "enable_x64", "describe"]
+           "worker_env", "enable_compile_cache", "set_host_device_count",
+           "set_platform", "enable_x64", "describe"]
 
 DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
+
+# The checkout this package runs from (src/repro/launch/env.py -> root).
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 
 
 def merged_xla_flags(existing: Optional[str], flag: str, value) -> str:
@@ -67,19 +74,45 @@ def host_device_flags(devices: int, existing: Optional[str] = None) -> str:
 
 
 def worker_env(devices: int, base: Optional[Mapping] = None,
-               platform: str = "cpu") -> dict:
+               platform: Optional[str] = None) -> dict:
     """The environment for one spawned worker process.
 
     ``base`` defaults to ``os.environ`` (the worker inherits PYTHONPATH,
-    locale, everything), with ``XLA_FLAGS`` rewritten to force
-    ``devices`` virtual devices and ``JAX_PLATFORMS`` pinned to
-    ``platform``. The returned dict is a copy — mutating it never
-    touches the parent's environment.
+    locale, ``JAX_PLATFORMS``, everything). ``platform`` pins
+    ``JAX_PLATFORMS``; ``None`` leaves the inherited value, so a worker
+    on a TPU host gets the chip unless its caller says otherwise. Only
+    when the worker's platform is ``cpu`` is ``XLA_FLAGS`` rewritten to
+    force ``devices`` virtual devices: the flag shapes the host platform
+    and means nothing to an accelerator. The returned dict is a copy —
+    mutating it never touches the parent's environment.
     """
     env = dict(os.environ if base is None else base)
-    env["XLA_FLAGS"] = host_device_flags(devices, env.get("XLA_FLAGS"))
-    env["JAX_PLATFORMS"] = platform
+    if platform is not None:
+        env["JAX_PLATFORMS"] = platform
+    if env.get("JAX_PLATFORMS") == "cpu":
+        env["XLA_FLAGS"] = host_device_flags(devices, env.get("XLA_FLAGS"))
     return env
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set. Otherwise the cache lives at ``.jax_cache/`` in
+    the checkout: a fixed path, so every process of every run finds what
+    an earlier one compiled. ``JAX_ENABLE_COMPILATION_CACHE=false`` (the
+    test suite sets it) turns the cache off and this returns ``None``.
+    Call it at the top of an entry point, before the first compile.
+    """
+    import jax
+
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _jax_initialized() -> bool:

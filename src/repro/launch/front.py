@@ -11,8 +11,8 @@ Two entry modes:
   talks to it except over RPC.
 * default — the orchestrated scenario (the CI front smoke gate):
   publish generation 0, spawn N replicas (child environments assembled
-  by :func:`repro.launch.env.worker_env` — single virtual device per
-  replica; lookups are one-chunk jits), boot the HTTP front over them,
+  by :func:`repro.launch.env.worker_env` — one CPU device per replica;
+  lookups are one-chunk jits), boot the HTTP front over them,
   then hammer ``/decide_batch`` from concurrent client threads **while
   the engine refreshes further generations with ``keep=2`` prune churn
   underneath** — the pointer watchers rebind the replicas live. Every
@@ -106,7 +106,7 @@ def spawn_replicas(root, n: int, cache_chunks: int = 32,
     """Spawn ``n`` replica processes and wait for their announcements.
 
     Child environments come from :func:`repro.launch.env.worker_env`
-    (platform pinned, ``devices`` virtual devices) with the running
+    (CPU platform, ``devices`` virtual devices) with the running
     package's ``src`` prepended to PYTHONPATH, same as the supervisor's
     workers. Returns ``(procs, clients)``; raises (after killing the
     children) if any replica dies or fails to announce in time.
@@ -114,7 +114,9 @@ def spawn_replicas(root, n: int, cache_chunks: int = 32,
     import os
 
     root = pathlib.Path(root)
-    wenv = envmod.worker_env(devices)
+    # A chip belongs to one process, and the parent's RefreshEngine holds
+    # it; replicas regenerate one chunk per lookup, which the CPU serves.
+    wenv = envmod.worker_env(devices, platform="cpu")
     src = str(pathlib.Path(__file__).resolve().parents[2])
     pp = wenv.get("PYTHONPATH", "")
     if src not in pp.split(os.pathsep):
@@ -460,6 +462,7 @@ def main() -> None:
                     help="replica mode: trace spans to <root>/obs/")
     args = ap.parse_args()
 
+    envmod.enable_compile_cache()
     if args.replica:
         if args.root is None:
             ap.error("--replica requires --root")

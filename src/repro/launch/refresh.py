@@ -55,6 +55,7 @@ from repro.core.faults import (
     resilient_source,
 )
 from repro.core.prefetch import solve_streaming_host
+from repro.launch.env import enable_compile_cache
 from repro.serve import RefreshEngine, WorkloadSpec, synthetic_source
 
 
@@ -286,6 +287,7 @@ def main():
                     help="bucket ladder half-width (smaller ladders "
                          "tighten the screening certificate)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.users, args.chunk, args.generations = 8192, 512, 3

@@ -47,6 +47,7 @@ from repro.core.chunked import solve_streaming
 from repro.core.instances import shard_key, sparse_instance
 from repro.core.prefetch import solve_streaming_host
 from repro.data.synth import sparse_chunk_source, sparse_host_chunk_source
+from repro.launch.env import enable_compile_cache
 
 
 def _mesh():
@@ -73,6 +74,8 @@ def run(workload: KPWorkload, cfg: SolverConfig, seed=0, mesh=None):
         res = solve_sharded(kp, mesh, cfg, q=q)
     else:
         res = solve(kp, cfg, q=q)
+    # Dispatch is asynchronous: without the wait, wall_s times the enqueue.
+    res = jax.block_until_ready(res)
     dt = time.time() - t0
     viol = float(jnp.max((res.r - kp.budgets) / kp.budgets))
     return {
@@ -123,6 +126,7 @@ def run_streaming(workload: KPWorkload, cfg: SolverConfig, chunk: int,
         if mesh is None:
             mesh = _mesh()
         res = solve_streaming(src, cfg, q=workload.q, mesh=mesh)
+    res = jax.block_until_ready(res)
     dt = time.time() - t0
     viol = float(jnp.max((res.r - src.budgets) / src.budgets))
     out = {
@@ -164,7 +168,8 @@ def main():
     ap.add_argument("--max-iters", type=int, default=40)
     ap.add_argument("--use-kernels", action="store_true",
                     help="Pallas kernel path (fused map+reduce for the "
-                         "sparse bucketed solve; interpret mode off-TPU)")
+                         "sparse bucketed solve; compiled on a TPU, "
+                         "interpreted on the CPU)")
     ap.add_argument("--chunk-size", type=int, default=None,
                     help="stream the per-iteration map over user chunks "
                          "of this size (bit-identical on the SCD bucketed "
@@ -213,6 +218,7 @@ def main():
                          "an escape below the floor reactivates every "
                          "chunk for one full pass")
     args = ap.parse_args()
+    enable_compile_cache()
 
     wl = WORKLOADS[args.workload]
     n = args.n or max(int(wl.n_users * args.scale), 1024)

@@ -44,10 +44,17 @@ fire cannot pass — the skip-proof convention of REQUIRE_HYPOTHESIS.
     PYTHONPATH=src python -m repro.launch.supervisor --supervise refresh \
         --root /tmp/sup --users 65536 --generations 3 --slots 4
 
-Worker environments are assembled by :mod:`repro.launch.env` — the
-degraded respawn is literally a smaller
+Worker environments are assembled by :mod:`repro.launch.env`. A worker
+inherits the coordinator's ``JAX_PLATFORMS`` unless the supervisor pins
+one, so on a TPU host the worker gets the chip; the coordinator itself
+never initialises a JAX backend, because a chip belongs to one process.
+The degraded respawn is literally a smaller
 ``--xla_force_host_platform_device_count`` in the child's ``XLA_FLAGS``,
-which is the same lever the multi-host roadmap item will drive per host.
+so it happens only when the workers' platform is ``cpu`` (the soak's CLI
+pins it for exactly that); an accelerator worker is respawned on the
+chips it has. Each worker writes ``worker.json`` (platform, device kind
+and count) once its backend is up; ``SUPERVISOR.json`` carries it under
+``"worker"`` and publishes its device count as ``devices``.
 """
 from __future__ import annotations
 
@@ -74,6 +81,7 @@ _STATUS = "SUPERVISOR.json"
 _FAILED = "FAILED.json"
 _TASK = "task.json"
 _HEARTBEAT = "heartbeat.json"
+_WORKER = "worker.json"
 _CLAIM_RE = re.compile(r"\.claim_(\d+)$")
 
 
@@ -89,8 +97,8 @@ class SupervisorConfig:
     bounds crash restarts plus hang takeovers together; exceeding it
     stamps ``FAILED.json`` and stops — the containment path, never a
     spin. ``degrade`` halves the worker device count on every respawn
-    (floor ``min_devices``), exercising elastic resume under real loss
-    of capacity. ``progress_ttl`` optionally adds stuck-fetch detection
+    (floor ``min_devices``) of a CPU-platform worker, exercising elastic
+    resume under real loss of capacity. ``progress_ttl`` optionally adds stuck-fetch detection
     (beats alive, progress frozen).
     """
 
@@ -178,7 +186,8 @@ class Supervisor:
         # counters for event tallies, gauges for the point-in-time
         # term/devices/lease-age readings); the :attr:`counters` dict
         # the rest of the stack consumes is assembled on read, with the
-        # same 13 keys SUPERVISOR.json has always published.
+        # 13 counter keys SUPERVISOR.json has always published, plus
+        # "worker": what the current worker's JAX reported (worker.json).
         self.registry = MetricsRegistry()
         self._ctrs = {
             k: self.registry.counter(f"supervisor_{k}")
@@ -190,7 +199,7 @@ class Supervisor:
         self._g_devices = self.registry.gauge("supervisor_devices")
         self._g_devices.set(self.devices0)
         self._g_lease_age = self.registry.gauge("supervisor_max_lease_age")
-        self._info = {"state": "init", "last_rc": None}
+        self._info = {"state": "init", "last_rc": None, "worker": None}
 
     @property
     def counters(self) -> dict:
@@ -210,6 +219,7 @@ class Supervisor:
             "term": int(self._g_term.value),
             "devices": int(self._g_devices.value),
             "last_rc": self._info["last_rc"],
+            "worker": self._info["worker"],
         }
 
     # -- spawn plumbing -----------------------------------------------------
@@ -261,6 +271,16 @@ class Supervisor:
 
     # -- status publication -------------------------------------------------
 
+    def _read_worker(self, term: int) -> Optional[dict]:
+        """The platform document ``term``'s worker wrote, once it has."""
+        from ..checkpoint import ckpt
+
+        try:
+            doc = ckpt.read_json(self.root, _WORKER)
+        except ValueError:
+            return None
+        return doc if doc is not None and doc.get("term") == term else None
+
     def _publish(self, state: str):
         from ..checkpoint import ckpt
 
@@ -286,6 +306,11 @@ class Supervisor:
                            grace=self.cfg.grace, expect_term=term,
                            progress_ttl=self.cfg.progress_ttl)
         while True:
+            if self._info["worker"] is None:
+                self._info["worker"] = self._read_worker(term)
+                if self._info["worker"] is not None:
+                    self._g_devices.set(self._info["worker"]["device_count"])
+                    self._publish("running")
             rc = proc.poll()
             st = mon.poll()
             if st["age"] is not None:
@@ -333,6 +358,10 @@ class Supervisor:
         self._g_devices.set(self.devices0)
         events = list(self.chaos.events) if self.chaos is not None else []
         devices = self.devices0
+        # Only the host platform's device count can be set from outside
+        # the worker; an accelerator worker sees the chips it has.
+        degrade = (self.cfg.degrade
+                   and self._env(devices).get("JAX_PLATFORMS") == "cpu")
         term = self._next_term()
         while True:
             if term > 1 and not claim_takeover(self.hb_path, term):
@@ -341,6 +370,7 @@ class Supervisor:
                     "was already held — another coordinator owns this "
                     "root; standing down instead of double-driving it")
             proc = self._spawn(term, devices)
+            self._info["worker"] = None
             self._ctrs["spawns"].inc()
             self._g_term.set(term)
             self._g_devices.set(devices)
@@ -374,7 +404,7 @@ class Supervisor:
                 self._publish("failed")
                 return self.counters
             term += 1
-            if self.cfg.degrade:
+            if degrade:
                 devices = max(self.cfg.min_devices, devices // 2)
 
 
@@ -490,6 +520,18 @@ def run_refresh_task(root, task: dict, hb=None) -> dict:
     return {"live": engine.live_gen_id()}
 
 
+def _announce_platform(root, term: int) -> None:
+    """Bring this worker's JAX backend up and record what it got."""
+    import jax
+
+    from ..checkpoint import ckpt
+
+    devs = jax.devices()
+    ckpt.write_json(root, _WORKER, {
+        "term": term, "pid": os.getpid(), "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind, "device_count": len(devs)})
+
+
 def _worker_main(args) -> int:
     """``--worker`` entry: heartbeat up, then run the durable task.
 
@@ -505,9 +547,11 @@ def _worker_main(args) -> int:
 
     from ..core.heartbeat import HeartbeatWriter
 
+    envmod.enable_compile_cache()
     hb = HeartbeatWriter(root / _HEARTBEAT, worker=task.get("kind", "task"),
                          term=args.term, ttl=float(task.get("ttl", 3.0)))
     with hb:
+        _announce_platform(root, args.term)
         if task["kind"] == "solve":
             run_solve_task(root, task, hb)
         elif task["kind"] == "refresh":
@@ -787,8 +831,13 @@ def main():
 
     import tempfile
 
+    envmod.enable_compile_cache()
     root = args.root or tempfile.mkdtemp(prefix="supervisor_")
     if args.chaos_soak:
+        # The soak drills degraded respawns, which only the host
+        # platform's virtual device count can fake, and compares its
+        # CPU workers bitwise against references run in this process.
+        envmod.set_platform("cpu")
         ok, _ = run_chaos_soak(root, smoke=args.smoke, seed=args.seed)
         sys.exit(0 if ok else 1)
     if args.supervise is not None:

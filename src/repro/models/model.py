@@ -21,9 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
-from ..compat import get_abstract_mesh
 from ..optim import OptConfig, apply_updates, init_opt_state
 from . import encdec as ed
 from . import lm, sharding

@@ -21,9 +21,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import get_abstract_mesh
 
-from ..compat import shard_map
-from ..compat import get_abstract_mesh
 from ..core.moe_router import scd_route, topk_route
 from .layers import truncnorm
 from . import sharding

@@ -18,9 +18,7 @@ import threading
 from typing import Optional
 
 import jax
-
-from ..compat import get_abstract_mesh
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 _state = threading.local()
 

@@ -378,7 +378,12 @@ class ReplicaServer:
             return out
         if op == "health":
             h = self.svc.health()
+            import jax
+
+            dev = jax.devices()[0]
             h["replica"] = {"index": self.index, "pid": os.getpid(),
+                            "platform": dev.platform,
+                            "device_kind": dev.device_kind,
                             "rebinds": self.rebinds,
                             "gen_cache": sorted(self._gen_services)}
             return h

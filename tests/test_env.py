@@ -6,6 +6,7 @@ initialised JAX (every test session imports jax), which is exactly the
 footgun the guard exists for.
 """
 import os
+import pathlib
 
 import pytest
 
@@ -45,9 +46,57 @@ def test_worker_env_pins_platform_and_devices_without_mutating_base():
 
 def test_worker_env_defaults_to_os_environ():
     out = env.worker_env(2)
-    assert out["JAX_PLATFORMS"] == "cpu"
+    # The platform is inherited, never chosen for the child.
+    assert out.get("JAX_PLATFORMS") == os.environ.get("JAX_PLATFORMS")
     # Inherits unrelated variables from the real environment.
     assert out.get("PATH") == os.environ.get("PATH")
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu"])
+def test_worker_env_fakes_no_device_count_off_cpu(platforms):
+    base = {"XLA_FLAGS": "--a=1"}
+    if platforms is not None:
+        base["JAX_PLATFORMS"] = platforms
+    out = env.worker_env(4, base=base)
+    assert out.get("JAX_PLATFORMS") == platforms
+    assert out["XLA_FLAGS"] == "--a=1"
+
+
+def test_worker_env_inherited_cpu_gets_device_count():
+    out = env.worker_env(4, base={"JAX_PLATFORMS": "cpu"})
+    assert out["XLA_FLAGS"] == "--xla_force_host_platform_device_count=4"
+
+
+def test_compile_cache_off_under_the_test_suite():
+    # tests/conftest.py switches the cache off for the whole suite.
+    assert jax.config.jax_enable_compilation_cache is False
+    assert env.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+@pytest.mark.parametrize("preset", [None, "/elsewhere/cache"])
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(
+        monkeypatch, preset):
+    if preset is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", preset)
+    jax.config.update("jax_enable_compilation_cache", True)
+    updates = []
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(jax.config, "update",
+                      lambda k, v: updates.append((k, v)))
+            got = env.enable_compile_cache()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", False)
+    if preset is None:
+        want = str(pathlib.Path(env.__file__).resolve().parents[3]
+                   / ".jax_cache")
+        assert got == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        assert got == preset and updates == []
 
 
 def test_setters_raise_after_jax_initialised():

@@ -257,6 +257,9 @@ def test_front_aggregated_health_and_failover(root):
         assert h["ok"] and h["agreement"]
         assert h["generations"] == [root.gens[-1].gen]
         assert [d["replica"]["index"] for d in h["replicas"]] == [0, 1]
+        # Each replica reports the platform its JAX actually came up on.
+        assert all(d["replica"]["platform"] == jax.devices()[0].platform
+                   for d in h["replicas"])
         assert all(d["supervisor"] == {"status": "absent"}
                    for d in h["replicas"])
         # Kill replica 0; the round-robin must fail over, health must

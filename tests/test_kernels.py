@@ -119,3 +119,63 @@ def test_solver_kernel_path_matches_jnp_path():
     np.testing.assert_allclose(np.asarray(a.lam), np.asarray(b.lam),
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(a.primal), float(b.primal), rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_only_on_cpu(monkeypatch, backend, want):
+    """The kernels compile on a TPU and interpret only on the CPU; any
+    other backend refuses rather than silently interpreting."""
+    from repro.kernels._util import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            resolve_interpret(None)
+    else:
+        assert resolve_interpret(None) is want
+
+
+_HIST_CONTRACTIONS = {
+    "finalize_kernel": lambda p, b, lam, pe, ed: jax.jit(
+        lambda *a: ops.scd_finalize_hist(*a, 1, tile_n=128)).lower(
+            p, b, lam, pe),
+    "finalize_ref": lambda p, b, lam, pe, ed: jax.jit(
+        lambda *a: ref.scd_finalize_ref(*a, 1)).lower(p, b, lam, pe),
+    "bucket_hist_ref": lambda p, b, lam, pe, ed: jax.jit(
+        ref.bucket_hist_ref).lower(p, b, ed),
+}
+
+
+@pytest.mark.parametrize("which", sorted(_HIST_CONTRACTIONS))
+def test_histogram_contractions_pin_highest_precision(which):
+    """One-hot histogram matmuls carry precision=HIGHEST in their lowered
+    HLO: at DEFAULT a TPU feeds their f32 consumption / profit operands to
+    the MXU as bf16, which the CPU cannot show numerically."""
+    p, b, lam = _inst(256, 10, jnp.float32)
+    pedges = jnp.linspace(-1.0, 1.0, 7, dtype=jnp.float32)
+    edges = jnp.tile(pedges, (10, 1))
+    text = _HIST_CONTRACTIONS[which](p, b, lam, pedges, edges).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert dots, "expected the histogram contraction in the lowered HLO"
+    assert all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots), dots
+
+
+def test_chip_smoke_precision_phase_runs_interpreted(capsys):
+    """chip_smoke.py phase (f), the on-chip bf16 check of the histogram
+    contractions, runs end to end under the interpreter: its float64
+    references agree with the kernels wherever operands stay f32."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    smoke.phase_precision(jax.devices()[0], n=1024)
+    out = capsys.readouterr().out
+    assert out.count("PASS") == 4, out
+    assert "control: the same contraction at DEFAULT precision reads rel " \
+        "0.000e+00" in out, out

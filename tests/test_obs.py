@@ -452,7 +452,8 @@ def test_supervisor_publish_routes_through_write_json(tmp_path, monkeypatch):
                         "hang_takeovers", "restarts", "kills_injected",
                         "stops_injected", "degraded_spawns",
                         "max_lease_age", "term", "devices", "last_rc",
-                        "updated_wall"}
+                        "worker", "updated_wall"}
+    assert doc["worker"] is None            # no worker has announced yet
     # And the durable file is what health() will read back.
     on_disk = json.loads((tmp_path / "SUPERVISOR.json").read_text())
     assert on_disk["state"] == "watching"

@@ -553,18 +553,18 @@ def test_host_sharded_matches_traced_sharded_subprocess(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Pre-screening regression pin + the screening/resume interplay.
+# Screening-inertness digest + the screening/resume interplay.
 # ---------------------------------------------------------------------------
 
 # sha256 over RESULT_FIELDS of the 8-virtual-device sharded solve on the
-# seeded fixture below, recorded immediately BEFORE active-set screening
-# (core/screening.py) landed. cfg.screening=False must keep producing
-# these exact bytes; screening=True must too on this uniform workload
-# (its chunk ratio maxima never clear the bucket ladder, so the active
-# set never shrinks and every epoch streams everything).
-_GOLDEN_SHARDED = \
-    "072a1ca1a405c827933ca8b387870d5415114bca09a220aefa027d47aa060f52"
-
+# seeded fixture below. The digest is compared across runs of one
+# process, never against a recorded value: its NumPy instance bytes are
+# fixed, but the solve's f32 arithmetic moves with the XLA build, so a
+# recorded pin breaks on every JAX upgrade. Screening on must reproduce
+# the unscreened bytes on this uniform workload (its chunk ratio maxima
+# never clear the bucket ladder, so the active set never shrinks), and
+# the 8 slots on one device must reproduce them on eight (mesh-size
+# invariance of the host-fed sharded driver).
 _GOLDEN_SHARDED_SCRIPT = textwrap.dedent("""
     import hashlib, os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -589,6 +589,11 @@ _GOLDEN_SHARDED_SCRIPT = textwrap.dedent("""
                                mesh=mesh, slots=8)
     assert bool(scr.screen["active"].all())
     print("SCREENED", digest(scr))
+    one = solve_streaming_host(
+        src, cfg, q=2, mesh=jax.make_mesh((1,), ("users",),
+                                          devices=jax.devices()[:1]),
+        slots=8)
+    print("ONE-DEVICE", digest(one))
 """)
 
 
@@ -600,8 +605,12 @@ def test_sharded_golden_digest_unchanged():
                          env=env, capture_output=True, text=True,
                          timeout=900, cwd=str(REPO))
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
-    assert f"PLAIN {_GOLDEN_SHARDED}" in out.stdout, out.stdout
-    assert f"SCREENED {_GOLDEN_SHARDED}" in out.stdout, out.stdout
+    got = dict(line.split() for line in out.stdout.splitlines()
+               if line.split()[:1] in (["PLAIN"], ["SCREENED"],
+                                       ["ONE-DEVICE"]))
+    assert set(got) == {"PLAIN", "SCREENED", "ONE-DEVICE"}, out.stdout
+    assert got["SCREENED"] == got["PLAIN"], out.stdout
+    assert got["ONE-DEVICE"] == got["PLAIN"], out.stdout
 
 
 def test_resume_across_screening_toggle_bitwise(tmp_path):

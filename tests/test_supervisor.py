@@ -102,7 +102,8 @@ def test_clean_completion_publishes_done_status(tmp_path):
                                                      "ttl": 0.4}
 
 
-def test_crash_restart_resumes_on_degraded_devices(tmp_path):
+def test_crash_restart_resumes_on_degraded_devices(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     seen = []
 
     def cmd(root, term, devices):
@@ -117,9 +118,40 @@ def test_crash_restart_resumes_on_degraded_devices(tmp_path):
     assert out["last_rc"] == 5
     assert out["degraded_spawns"] == 1
     assert seen == [(1, 4), (2, 2)], "respawn must halve the devices"
-    # The respawn env forces the degraded device count on the child.
+    # The respawn env forces the degraded device count on the CPU child.
     env2 = sup._env(2)
+    assert env2["JAX_PLATFORMS"] == "cpu"
     assert "--xla_force_host_platform_device_count=2" in env2["XLA_FLAGS"]
+
+
+def test_accelerator_respawn_keeps_the_devices_it_has(tmp_path, monkeypatch):
+    """A non-CPU worker cannot be given fewer chips from outside: no
+    degraded respawn is counted, and ``devices`` is the count the worker
+    reported, not the slot count asked for."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    seen = []
+
+    def cmd(root, term, devices):
+        seen.append((term, devices))
+        # What the worker's JAX would announce once its backend is up.
+        ckpt.write_json(root, "worker.json", {
+            "term": term, "platform": "tpu", "device_kind": "TPU v5 lite",
+            "device_count": 1})
+        return [sys.executable, "-c", _FAKE, str(root), str(term),
+                "crash-once"]
+
+    sup = Supervisor(tmp_path, {"kind": "noop"}, cfg=_cfg(), devices=4,
+                     worker_cmd=cmd)
+    out = sup.run()
+    assert out["ok"] and out["crash_restarts"] == 1
+    assert seen == [(1, 4), (2, 4)], "an accelerator respawn never degrades"
+    assert out["degraded_spawns"] == 0
+    assert out["devices"] == 1 == out["worker"]["device_count"]
+    assert "--xla_force_host_platform_device_count" not in \
+        sup._env(2).get("XLA_FLAGS", "")
+    status = ckpt.read_json(tmp_path, "SUPERVISOR.json")
+    assert status["devices"] == 1 and status["degraded_spawns"] == 0
 
 
 def test_hang_detected_by_lease_expiry_and_taken_over(tmp_path):
@@ -223,7 +255,39 @@ def test_supervised_solve_matches_inprocess_reference(tmp_path):
                      devices=1)
     out = sup.run()
     assert out["ok"], out
+    # The worker reported the platform its own JAX came up on.
+    import jax
+    assert out["worker"]["term"] == 1
+    assert out["worker"]["platform"] == jax.devices()[0].platform
+    assert ckpt.read_json(tmp_path / "sup", "SUPERVISOR.json")["worker"] \
+        == out["worker"]
     got = ckpt.restore_auto(tmp_path / "sup" / "result", 0)
     for f in ["lam", "tau", "iters", "r", "primal", "dual"]:
         assert np.asarray(ref[f]).tobytes() \
             == np.asarray(got[f]).tobytes(), f
+
+
+def test_supervise_parent_never_initialises_jax(tmp_path):
+    """A chip belongs to one process: the ``--supervise`` coordinator
+    leaves the JAX backend to its worker and never initialises one."""
+    script = (
+        "import sys\n"
+        "from repro.launch import supervisor\n"
+        "sys.argv = ['supervisor', '--supervise', 'solve', '--users', "
+        "'2048', '--chunk', '512', '--max-iters', '5', '--slots', '1', "
+        f"'--root', {str(tmp_path)!r}]\n"
+        "try:\n"
+        "    supervisor.main()\n"
+        "except SystemExit as e:\n"
+        "    assert e.code == 0, e.code\n"
+        "from jax._src import xla_bridge\n"
+        "print('PARENT-INITIALISED', xla_bridge.backends_are_initialized())\n")
+    env = dict(os.environ)
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "PARENT-INITIALISED False" in out.stdout, out.stdout
+    status = ckpt.read_json(tmp_path, "SUPERVISOR.json")
+    assert status["ok"] and status["worker"]["term"] == 1
