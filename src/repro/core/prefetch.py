@@ -488,27 +488,35 @@ def source_fingerprint(source: HostChunkSource, cfg: SolverConfig, q: int,
 
 
 def _save_state(directory, step, phase, iters, cursor, slots, fp, lam,
-                dprev, fin, keep=3):
+                dprev, fin, keep=3, tracer=NULL_TRACER):
     """Write one StreamCheckpointState atomically; prune old steps.
 
-    ``fin`` is the per-slot fused-finalize partial tuple (leading axis =
-    slots; 5 or 7 leaves) — zeros while still iterating. Everything is
-    host-gathered NumPy, constant size in n. ``keep`` is the retention
+    ``fin()`` returns the per-slot fused-finalize partial tuple (leading
+    axis = slots; 5 or 7 leaves) — zeros while still iterating. Everything
+    is host-gathered NumPy, constant size in n. ``keep`` is the retention
     passed through to ``ckpt.prune`` (``cfg.checkpoint_keep``).
+
+    Spans: ``ckpt.save`` around the whole save, holding ``ckpt.gather``
+    (the host reads of the state; reading a device carry waits for the
+    device, which is why ``fin`` is called inside it) and ``ckpt.write``
+    (the files, their fsyncs and the prune).
     """
-    state = {
-        "phase": np.int32(phase),
-        "iters": np.int32(iters),
-        "cursor": np.int32(cursor),
-        "slots": np.int32(slots),
-        "fingerprint": np.asarray(fp, np.uint8),
-        "lam": np.asarray(lam),
-        "dprev": np.asarray(dprev),
-    }
-    for name, arr in zip(_FIN_KEYS, fin):
-        state[name] = np.asarray(arr)
-    ckpt.save(directory, step, state)
-    ckpt.prune(directory, keep=keep)
+    with tracer.span("ckpt.save", step=step):
+        with tracer.span("ckpt.gather"):
+            state = {
+                "phase": np.int32(phase),
+                "iters": np.int32(iters),
+                "cursor": np.int32(cursor),
+                "slots": np.int32(slots),
+                "fingerprint": np.asarray(fp, np.uint8),
+                "lam": np.asarray(lam),
+                "dprev": np.asarray(dprev),
+            }
+            for name, arr in zip(_FIN_KEYS, fin()):
+                state[name] = np.asarray(arr)
+        with tracer.span("ckpt.write"):
+            ckpt.save(directory, step, state)
+            ckpt.prune(directory, keep=keep)
 
 
 def _load_state(resume_from, mesh, axes):
@@ -885,9 +893,8 @@ class _SingleRuntime:
 
         lam_n, d_n, moved, trusted = run(obs, indices=idx)
         scr.record_streamed(len(idx))
-        if self.tracer.enabled:
-            self.tracer.event("screen.skip", streamed=len(idx),
-                              skipped=self.real_c - len(idx))
+        self.tracer.event("screen.skip", streamed=len(idx),
+                          skipped=self.real_c - len(idx))
         if scr.any_retired() and not bool(trusted):
             lam_n, d_n, moved, _ = run(src)
             scr.record_streamed(self.real_c, fallback=True)
@@ -997,20 +1004,13 @@ class _ShardedRuntime:
         # Same cfg.dtype cast as the single-device _put_chunk, so a
         # source producing wider arrays feeds both runtimes identically.
         dt = np.dtype(self.cfg.dtype)
-        tracer = self.tracer
-        if tracer.enabled:
-            with tracer.span("ingest.fetch", col=int(j)):
-                ps, bs = self._fetch_cols(j, screen, dt)
-            with tracer.span("ingest.h2d", col=int(j)):
-                pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
-                bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
-                return (jax.device_put(pb, self.slot_sh),
-                        jax.device_put(bb, self.slot_sh))
-        ps, bs = self._fetch_cols(j, screen, dt)
-        pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
-        bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
-        return (jax.device_put(pb, self.slot_sh),
-                jax.device_put(bb, self.slot_sh))
+        with self.tracer.span("ingest.fetch", col=int(j)):
+            ps, bs = self._fetch_cols(j, screen, dt)
+        with self.tracer.span("ingest.h2d", col=int(j)):
+            pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
+            bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
+            return (jax.device_put(pb, self.slot_sh),
+                    jax.device_put(bb, self.slot_sh))
 
     def _epoch_cols(self, step, state, extra, start=0, on_col=None,
                     indices=None, screen=False):
@@ -1094,9 +1094,8 @@ class _ShardedRuntime:
 
         lam_n, d_n, moved, trusted = run(indices=cols, screen=True)
         scr.record_streamed(streamed)
-        if self.tracer.enabled:
-            self.tracer.event("screen.skip", streamed=streamed,
-                              skipped=self.real_c - streamed)
+        self.tracer.event("screen.skip", streamed=streamed,
+                          skipped=self.real_c - streamed)
         if scr.any_retired() and not bool(trusted):
             lam_n, d_n, moved, _ = run()
             scr.record_streamed(self.real_c, fallback=True)
@@ -1191,9 +1190,14 @@ def solve_streaming_host(source: HostChunkSource,
     cannot be combined with checkpoint/resume.
 
     Observability: ``tracer`` (a :class:`repro.obs.Tracer`; default the
-    shared no-op) emits host-side phase spans — ``solve.iterate``,
-    ``solve.finalize``, ``ingest.fetch``, ``ingest.h2d``, ``screen.skip``
-    — to its JSONL journal. Tracing is *not* a ``SolverConfig`` field:
+    shared no-op) emits host-side phase spans to its JSONL journal. At
+    the top level they tile the solve: ``solve.fingerprint`` (resume
+    state and identity hash), one ``solve.iterate`` per iteration
+    (epoch, convergence wait, resume-state save) and ``solve.finalize``
+    (finalize-entry save, the finalize pass and its result). Inside
+    them: ``ckpt.save`` with ``ckpt.gather`` and ``ckpt.write`` per
+    resume-state save, ``ingest.fetch`` / ``ingest.h2d`` and
+    ``screen.skip``. Tracing is *not* a ``SolverConfig`` field:
     it never enters the resume fingerprint, and because spans bracket
     only host Python (never a value inside a jitted program), a traced
     solve is bitwise identical to an untraced one (``tests/test_obs.py``
@@ -1233,49 +1237,52 @@ def solve_streaming_host(source: HostChunkSource,
             "with checkpoint/resume (the sampled rows are not part of "
             "the constant-size resume state)")
 
-    restored = (_load_state(resume_from,
-                            mesh, tuple(mesh.axis_names) if mesh else None)
-                if resume_from is not None else None)
-    if restored is not None:
-        S = int(restored["slots"])
-        if slots is not None and slots != S:
-            raise ValueError(
-                f"checkpoint was written with slots={S}; asked for "
-                f"slots={slots} (the slot count is fixed at first launch)")
-    else:
-        S = slots if slots is not None else (
-            mesh.devices.size if mesh is not None else 1)
-    if mesh is None and S > 1:
-        # Degraded all the way down to one process-default device: run
-        # the same slot structure on an internal single-device mesh.
-        mesh = jax.make_mesh((1,), ("slots",))
-    if mesh is not None:
-        d = mesh.devices.size
-        if S < d or S % d != 0:
-            raise ValueError(
-                f"slots={S} must be a positive multiple of the mesh "
-                f"device count {d} (elastic resume divides slots over "
-                f"devices)")
-    sharded = mesh is not None
-    if sharded and cfg.stream_finalize == "legacy":
-        raise ValueError(
-            "sharded host feeding supports stream_finalize='fused' only "
-            "(the legacy three-pass finalize remains on the single-device "
-            "driver as the oracle/benchmark baseline)")
-
-    dtype = cfg.dtype
-    lam = (jnp.ones((source.k,), dtype) if lam0 is None
-           else jnp.asarray(lam0, dtype))
-    fp = (_fingerprint(source, cfg, q, np.asarray(lam))
-          if (checkpointing or restored is not None) else None)
-    if restored is not None and not np.array_equal(
-            np.asarray(restored["fingerprint"], np.uint8), fp):
-        raise ValueError(
-            "resume state fingerprint mismatch: the checkpoint in "
-            f"{resume_from!r} was written for a different "
-            "(source, cfg, q, lam0) — refusing to resume")
-
     tracer = NULL_TRACER if tracer is None else tracer
+    # The resume state and the identity hash: host reads of the
+    # checkpoint directory and of chunk 0, before any solve work.
+    with tracer.span("solve.fingerprint"):
+        restored = (_load_state(resume_from,
+                                mesh, tuple(mesh.axis_names) if mesh else None)
+                    if resume_from is not None else None)
+        if restored is not None:
+            S = int(restored["slots"])
+            if slots is not None and slots != S:
+                raise ValueError(
+                    f"checkpoint was written with slots={S}; asked for "
+                    f"slots={slots} (the slot count is fixed at first launch)")
+        else:
+            S = slots if slots is not None else (
+                mesh.devices.size if mesh is not None else 1)
+        if mesh is None and S > 1:
+            # Degraded all the way down to one process-default device: run
+            # the same slot structure on an internal single-device mesh.
+            mesh = jax.make_mesh((1,), ("slots",))
+        if mesh is not None:
+            d = mesh.devices.size
+            if S < d or S % d != 0:
+                raise ValueError(
+                    f"slots={S} must be a positive multiple of the mesh "
+                    f"device count {d} (elastic resume divides slots over "
+                    f"devices)")
+        sharded = mesh is not None
+        if sharded and cfg.stream_finalize == "legacy":
+            raise ValueError(
+                "sharded host feeding supports stream_finalize='fused' only "
+                "(the legacy three-pass finalize remains on the single-device "
+                "path as the oracle/benchmark baseline)")
+
+        dtype = cfg.dtype
+        lam = (jnp.ones((source.k,), dtype) if lam0 is None
+               else jnp.asarray(lam0, dtype))
+        fp = (_fingerprint(source, cfg, q, np.asarray(lam))
+              if (checkpointing or restored is not None) else None)
+        if restored is not None and not np.array_equal(
+                np.asarray(restored["fingerprint"], np.uint8), fp):
+            raise ValueError(
+                "resume state fingerprint mismatch: the checkpoint in "
+                f"{resume_from!r} was written for a different "
+                "(source, cfg, q, lam0) — refusing to resume")
+
     rt = (_ShardedRuntime(source, cfg, q, mesh, S, double_buffer) if sharded
           else _SingleRuntime(source, cfg, q, double_buffer))
     rt.tracer = tracer
@@ -1309,71 +1316,63 @@ def solve_streaming_host(source: HostChunkSource,
                                   cfg.profit_buckets + 1, cfg.postprocess,
                                   cfg.dtype)
 
+    save = functools.partial(_save_state, checkpoint_dir, slots=S, fp=fp,
+                             keep=cfg.checkpoint_keep, tracer=tracer)
+    fresh_finalize = phase == _PHASE_ITER
     if phase == _PHASE_ITER:
         while iters < cfg.max_iters:
-            if tracer.enabled:
-                with tracer.span("solve.iterate", iter=iters):
-                    lam, dprev, moved = rt.iter_epoch(lam, dprev)
-            else:
+            # The whole iteration: the epoch, the wait on ``moved`` and
+            # the iteration's resume-state save.
+            with tracer.span("solve.iterate", iter=iters):
                 lam, dprev, moved = rt.iter_epoch(lam, dprev)
-            iters += 1
-            if rows is not None:
-                if (iters - 1) % every == 0:
-                    rows.append(rt.metrics_record(lam))
-                else:
-                    nan = jnp.asarray(jnp.nan, lam.dtype)
-                    rows.append({"lam": lam, "primal": nan, "dual": nan,
-                                 "gap": nan, "max_violation": nan})
-            if not bool(moved):
-                break
-            if (checkpointing and iters % ckpt_every == 0
-                    and iters < cfg.max_iters):
-                _save_state(checkpoint_dir, iters, _PHASE_ITER, iters, 0,
-                            S, fp, lam, dprev, fin_zeros(),
-                            keep=cfg.checkpoint_keep)
-        phase, cursor = _PHASE_FIN, 0
-        if checkpointing:
+                iters += 1
+                if rows is not None:
+                    if (iters - 1) % every == 0:
+                        rows.append(rt.metrics_record(lam))
+                    else:
+                        nan = jnp.asarray(jnp.nan, lam.dtype)
+                        rows.append({"lam": lam, "primal": nan, "dual": nan,
+                                     "gap": nan, "max_violation": nan})
+                if not bool(moved):
+                    break
+                if (checkpointing and iters % ckpt_every == 0
+                        and iters < cfg.max_iters):
+                    save(iters, _PHASE_ITER, iters, 0, lam=lam, dprev=dprev,
+                         fin=fin_zeros)
+
+    with tracer.span("solve.finalize", mode=cfg.stream_finalize,
+                     iters=iters):
+        if checkpointing and fresh_finalize:
             # Finalize-entry state: without it, a kill during the
             # finalize would force replaying multiplier iterations.
-            _save_state(checkpoint_dir, cfg.max_iters + 1, _PHASE_FIN,
-                        iters, 0, S, fp, lam, dprev, fin_zeros(),
-                        keep=cfg.checkpoint_keep)
+            save(cfg.max_iters + 1, _PHASE_FIN, iters, 0, lam=lam,
+                 dprev=dprev, fin=fin_zeros)
 
-    history = None
-    if rows is not None:
-        # The traced scan driver freezes converged iterations: every row
-        # past convergence re-records the final iteration's sample —
-        # which is exactly a copy of the last live row (the sampling
-        # predicate is keyed on the frozen iteration number).
-        while len(rows) < cfg.max_iters:
-            rows.append(rows[-1])
-        history = {k: jnp.stack([r[k] for r in rows]) for k in rows[0]}
+        history = None
+        if rows is not None:
+            # The traced scan solve freezes converged iterations: every
+            # row past convergence re-records the final iteration's
+            # sample — which is exactly a copy of the last live row (the
+            # sampling predicate is keyed on the frozen iteration number).
+            while len(rows) < cfg.max_iters:
+                rows.append(rows[-1])
+            history = {k: jnp.stack([r[k] for r in rows]) for k in rows[0]}
 
-    scr_stats = scr.stats() if scr is not None else None
-    if cfg.stream_finalize == "legacy":
-        if tracer.enabled:
-            with tracer.span("solve.finalize", mode="legacy", iters=iters):
-                res = rt.legacy_result(lam, iters)
-        else:
+        scr_stats = scr.stats() if scr is not None else None
+        if cfg.stream_finalize == "legacy":
             res = rt.legacy_result(lam, iters)
-        return res._replace(history=history, screen=scr_stats)
+            return res._replace(history=history, screen=scr_stats)
 
-    on_col = None
-    if checkpointing:
-        def on_col(j, state):
-            done = j + 1
-            if done % ckpt_every == 0 and done < rt.fin_cols:
-                _save_state(checkpoint_dir, cfg.max_iters + 1 + done,
-                            _PHASE_FIN, iters, done, S, fp, lam, dprev,
-                            rt.fin_to_np(state), keep=cfg.checkpoint_keep)
+        on_col = None
+        if checkpointing:
+            def on_col(j, state):
+                done = j + 1
+                if done % ckpt_every == 0 and done < rt.fin_cols:
+                    save(cfg.max_iters + 1 + done, _PHASE_FIN, iters, done,
+                         lam=lam, dprev=dprev,
+                         fin=functools.partial(rt.fin_to_np, state))
 
-    carry = rt.fin_init() if fin_carry is None else fin_carry
-    if tracer.enabled:
-        with tracer.span("solve.finalize", mode="fused", iters=iters):
-            carry = rt.fin_run(carry, lam, cursor, on_col)
-            res = rt.fin_result(carry, lam, iters)
-    else:
+        carry = rt.fin_init() if fin_carry is None else fin_carry
         carry = rt.fin_run(carry, lam, cursor, on_col)
         res = rt.fin_result(carry, lam, iters)
     return res._replace(history=history, screen=scr_stats)
-

@@ -483,23 +483,28 @@ def _solve_local(kp, lam0, q, cfg, axis=None):
             "max_violation": viol,
         }
 
-    lam, iters, hist = iterate_multipliers(
-        lambda lam: update(lam), lam0, cfg, metrics_fn
-    )
+    # Named scopes mark each phase's ops in a profile (op metadata only).
+    with jax.named_scope("iterate_multipliers"):
+        lam, iters, hist = iterate_multipliers(
+            lambda lam: update(lam), lam0, cfg, metrics_fn
+        )
 
     # Final primal + §5.4 feasibility projection.
-    x, cons, r, primal, dual, _ = _metrics(kp, lam, q, axis)
+    with jax.named_scope("final_primal"):
+        x, cons, r, primal, dual, _ = _metrics(kp, lam, q, axis)
     if cfg.postprocess:
-        pt = group_profit(kp.p, cons, lam, x)
-        if axis is None:
-            tau = feasibility_threshold_exact(pt, cons, kp.budgets)
-        else:
-            tau = feasibility_threshold_bucketed(pt, cons, r, kp.budgets, axis)
-        drop = pt <= tau
-        x = x & ~drop[:, None]
-        cons = cons * (~drop[:, None]).astype(cons.dtype)
-        r = _psum(jnp.sum(cons, axis=0), axis)
-        primal = _psum(jnp.sum(jnp.where(x, kp.p, 0.0)), axis)
+        with jax.named_scope("postprocess"):
+            pt = group_profit(kp.p, cons, lam, x)
+            if axis is None:
+                tau = feasibility_threshold_exact(pt, cons, kp.budgets)
+            else:
+                tau = feasibility_threshold_bucketed(pt, cons, r,
+                                                     kp.budgets, axis)
+            drop = pt <= tau
+            x = x & ~drop[:, None]
+            cons = cons * (~drop[:, None]).astype(cons.dtype)
+            r = _psum(jnp.sum(cons, axis=0), axis)
+            primal = _psum(jnp.sum(jnp.where(x, kp.p, 0.0)), axis)
     return SolveResult(lam, x, iters, r, primal, dual, hist)
 
 
@@ -525,7 +530,8 @@ def _presolve(kp, lam0, q, cfg, axis):
 
 
 def _solve_entry(kp, lam0, q, cfg, axis):
-    lam0 = _presolve(kp, lam0, q, cfg, axis)
+    with jax.named_scope("presolve"):
+        lam0 = _presolve(kp, lam0, q, cfg, axis)
     return _solve_local(kp, lam0, q, cfg, axis)
 
 

@@ -32,6 +32,10 @@ absorbed every fault, no reader ever saw a torn or stale byte.
     PYTHONPATH=src python -m repro.launch.refresh --smoke --chaos
     PYTHONPATH=src python -m repro.launch.refresh --users 1000000 \
         --generations 7 --root /tmp/refresh
+
+``--obs`` journals the refreshes' phase spans under ``<root>/obs/``
+(:func:`repro.obs.make_obs`, role ``refresh``) for the operator to read
+with ``tools/trace_view.py <root>``.
 """
 from __future__ import annotations
 
@@ -56,6 +60,7 @@ from repro.core.faults import (
 )
 from repro.core.prefetch import solve_streaming_host
 from repro.launch.env import enable_compile_cache
+from repro.obs import make_obs
 from repro.serve import RefreshEngine, WorkloadSpec, synthetic_source
 
 
@@ -106,10 +111,12 @@ def _verify_lookups(engine: RefreshEngine, svc, users) -> bool:
 
 def run_scenario(spec: WorkloadSpec, generations: int, root,
                  cfg: SolverConfig, mesh=None, slots=None, lookups=512,
-                 verify=True, resume=False, make_source=synthetic_source):
-    """The multi-day loop; returns the accounting dict the bench reuses."""
+                 verify=True, resume=False, make_source=synthetic_source,
+                 obs=None):
+    """The multi-day loop; returns the accounting dict the bench reuses.
+    ``obs`` is the engine's observability bundle (default: none)."""
     engine = RefreshEngine(root, spec, make_source=make_source, cfg=cfg,
-                           mesh=mesh, slots=slots)
+                           mesh=mesh, slots=slots, obs=obs)
     if resume:
         rec = engine.recover()
         if rec is not None:
@@ -286,6 +293,9 @@ def main():
     ap.add_argument("--bucket-half", type=int, default=24,
                     help="bucket ladder half-width (smaller ladders "
                          "tighten the screening certificate)")
+    ap.add_argument("--obs", action="store_true",
+                    help="journal the refreshes' phase spans under "
+                         "<root>/obs/ (read with tools/trace_view.py)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -309,9 +319,16 @@ def main():
         ok, _ = run_chaos(spec, args.generations, root, cfg, mesh=mesh,
                           slots=args.slots, lookups=args.lookups)
         sys.exit(0 if ok else 1)
-    out = run_scenario(spec, args.generations, root, cfg, mesh=mesh,
-                       slots=args.slots, lookups=args.lookups,
-                       verify=not args.no_verify, resume=args.resume)
+    obs = make_obs(root, role="refresh") if args.obs else None
+    try:
+        out = run_scenario(spec, args.generations, root, cfg, mesh=mesh,
+                           slots=args.slots, lookups=args.lookups,
+                           verify=not args.no_verify, resume=args.resume,
+                           obs=obs)
+    finally:
+        if obs is not None:
+            obs.close()
+            print(f"[refresh] span journal {obs.tracer.path}")
     if out["warm_refreshes"] \
             and out["warm_iters_total"] >= out["cold_iters_total"]:
         print("[refresh] FAIL: warm refreshes did not beat cold "

@@ -6,6 +6,12 @@ inside jitted code — no wall-clock or counter read is ever traced into
 an XLA program, which is what keeps a solve with tracing enabled
 bitwise identical to one without (DESIGN.md §14).
 
+Each span also opens a ``jax.profiler.TraceAnnotation`` of its phase,
+so a profile taken of the process shows the spans on the host line,
+on the device trace's clock, beside the ops the device ran; with no
+profiler session active the annotation costs about a microsecond. A
+process that has not loaded JAX opens none (it cannot be profiled).
+
 Journal format: one JSON object per line —
 
     {"phase": "solve.iterate", "t": <epoch s>, "dur_s": <float>,
@@ -30,6 +36,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
@@ -63,8 +70,16 @@ def trace_path(root, role: str):
                         f"{role}-{os.getpid()}.jsonl")
 
 
+def _annotation(phase):
+    """A profiler annotation named ``phase``, or None where JAX is not
+    loaded (no profiler session can run in such a process). Looked up,
+    never imported: a span must not pull JAX into a host-only process."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(phase)
+
+
 class _Span:
-    __slots__ = ("_tracer", "_phase", "_attrs", "_t0", "_p0")
+    __slots__ = ("_tracer", "_phase", "_attrs", "_t0", "_p0", "_ann")
 
     def __init__(self, tracer, phase, attrs):
         self._tracer = tracer
@@ -72,12 +87,17 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self):
+        self._ann = _annotation(self._phase)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.time()
         self._p0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._p0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer._emit(self._phase, self._t0, dur, self._attrs)
         return False
 

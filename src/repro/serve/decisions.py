@@ -298,19 +298,11 @@ class DecisionService:
                 self._cache.move_to_end(key)
                 return hit
         t0 = time.perf_counter()
-        tracer = self._tracer
-        if tracer.enabled:
-            # The fill span carries the request id installed by the
-            # replica RPC layer (repro.obs.trace.request), correlating a
-            # front HTTP request with the fill that served it.
-            with tracer.span("serve.fill", chunk=int(ci),
-                             gen=bound.generation.gen):
-                p, b = self._fetch(bound, ci)
-                rows = (ci * bound.source.chunk
-                        + np.arange(bound.source.chunk))
-                valid = jnp.asarray(rows < bound.source.n)
-                x = np.asarray(bound.fn(p, b, bound.lam, valid, bound.tau))
-        else:
+        # The fill span carries the request id installed by the replica
+        # RPC layer (repro.obs.trace.request), correlating a front HTTP
+        # request with the fill that served it.
+        with self._tracer.span("serve.fill", chunk=int(ci),
+                               gen=bound.generation.gen):
             p, b = self._fetch(bound, ci)
             rows = ci * bound.source.chunk + np.arange(bound.source.chunk)
             valid = jnp.asarray(rows < bound.source.n)

@@ -227,6 +227,18 @@ class Generation(NamedTuple):
     path: str              # this generation's directory
 
 
+class _Job(NamedTuple):
+    """A prepared refresh: its durable intent is on disk."""
+
+    gen_id: int
+    spec: WorkloadSpec
+    gdir: pathlib.Path
+    record_done: bool
+    source: Optional[HostChunkSource]
+    lam0: Optional[jnp.ndarray]
+    screen_init: Optional[dict]
+
+
 class RefreshEngine:
     """Immutable-generation refresh driver over one root directory.
 
@@ -381,25 +393,28 @@ class RefreshEngine:
         discard the pending generation first — two concurrent intents
         for the same generation id cannot both be honoured).
         """
-        live = self.live()
-        spec = (live.spec if live is not None else self.base_spec).replace(
-            **deltas)
-        gen_id = live.gen + 1 if live is not None else 0
-        warm = bool(warm and live is not None)   # effective: gen 0 is cold
+        with self.obs.tracer.span("refresh.prepare"):
+            live = self.live()
+            spec = (live.spec if live is not None
+                    else self.base_spec).replace(**deltas)
+            gen_id = live.gen + 1 if live is not None else 0
+            warm = bool(warm and live is not None)  # effective: gen 0 cold
 
-        pending = self._pending()
-        if pending is not None:
-            pend_id, meta = pending
-            pend_spec = WorkloadSpec.from_json(meta["spec"])
-            if pend_spec != spec or bool(meta["warm"]) != warm:
-                raise ValueError(
-                    f"generation {pend_id} is already pending with spec "
-                    f"{pend_spec} (warm={meta['warm']}) but this refresh "
-                    f"asked for {spec} (warm={warm}); resume the pending "
-                    "refresh by repeating its deltas (or recover()), or "
-                    f"delete {self._gen_dir(pend_id)} to discard it")
-            return self._run(pend_id, pend_spec, bool(meta["warm"]), live)
-        return self._run(gen_id, spec, warm, live)
+            pending = self._pending()
+            if pending is not None:
+                pend_id, meta = pending
+                pend_spec = WorkloadSpec.from_json(meta["spec"])
+                if pend_spec != spec or bool(meta["warm"]) != warm:
+                    raise ValueError(
+                        f"generation {pend_id} is already pending with "
+                        f"spec {pend_spec} (warm={meta['warm']}) but this "
+                        f"refresh asked for {spec} (warm={warm}); resume "
+                        "the pending refresh by repeating its deltas (or "
+                        f"recover()), or delete {self._gen_dir(pend_id)} "
+                        "to discard it")
+                gen_id, spec, warm = pend_id, pend_spec, bool(meta["warm"])
+            job = self._prepare(gen_id, spec, warm, live)
+        return self._run(job)
 
     def recover(self) -> Optional[Generation]:
         """Finish a preempted refresh, if any; None when nothing pends.
@@ -414,9 +429,11 @@ class RefreshEngine:
         if pending is None:
             return None
         gen_id, meta = pending
-        spec = WorkloadSpec.from_json(meta["spec"])
-        parent = self.live()
-        return self._run(gen_id, spec, bool(meta["warm"]), parent)
+        with self.obs.tracer.span("refresh.prepare"):
+            spec = WorkloadSpec.from_json(meta["spec"])
+            parent = self.live()
+            job = self._prepare(gen_id, spec, bool(meta["warm"]), parent)
+        return self._run(job)
 
     def _parent_screen(self, parent: Generation) -> Optional[dict]:
         """The parent generation's screening artifacts, or None when the
@@ -429,12 +446,15 @@ class RefreshEngine:
                 "bmax": np.asarray(state["screen_bmax"], np.float32),
                 "lam_lo": np.asarray(state["screen_lam_lo"], np.float32)}
 
-    def _run(self, gen_id: int, spec: WorkloadSpec, warm: bool,
-             parent: Optional[Generation]) -> Generation:
+    def _prepare(self, gen_id: int, spec: WorkloadSpec, warm: bool,
+                 parent: Optional[Generation]) -> _Job:
+        """Everything before the solve: the source, the warm start, the
+        durable intent and the delta refresh's screening seed. Callers
+        run it, and their read of the live generation, inside one
+        ``refresh.prepare`` span."""
         gdir = self._gen_dir(gen_id)
-        ckdir = gdir / "ckpt"
         record_done = ckpt.latest_step(gdir / "record") is not None
-        source, lam0 = None, None
+        source, lam0, screen_init = None, None, None
         if not record_done:
             # Validate the refresh and construct its source BEFORE the
             # intent becomes durable: an invalid call (bad deltas, a
@@ -457,8 +477,8 @@ class RefreshEngine:
             "warm": bool(warm and parent is not None),
             "parent": None if parent is None else parent.gen,
         })
-
-        if not record_done:
+        if (not record_done and self.cfg.screening and parent is not None
+                and self.chunk_diff is not None):
             # Delta refresh: seed the new solve's active set from the
             # parent generation's published screening certificates —
             # unchanged chunks start retired (never re-streamed unless
@@ -466,20 +486,29 @@ class RefreshEngine:
             # start active with unknown bounds. Recomputed identically
             # on every re-entry (the parent record is immutable), so a
             # resumed refresh still publishes the bitwise record.
-            screen_init = None
-            if (self.cfg.screening and parent is not None
-                    and self.chunk_diff is not None):
-                seed_state = self._parent_screen(parent)
-                changed = self.chunk_diff(parent.spec, spec)
-                if seed_state is not None and changed is not None:
-                    seed_state["changed"] = np.asarray(changed, bool)
-                    screen_init = seed_state
+            seed_state = self._parent_screen(parent)
+            changed = self.chunk_diff(parent.spec, spec)
+            if seed_state is not None and changed is not None:
+                seed_state["changed"] = np.asarray(changed, bool)
+                screen_init = seed_state
+        return _Job(gen_id, spec, gdir, record_done, source, lam0,
+                    screen_init)
+
+    def _run(self, job: _Job) -> Generation:
+        """Solve, stamp and publish a prepared generation, then read it
+        back. Spans: ``refresh.stamp``, ``refresh.publish`` (record,
+        then pointer) and ``refresh.readback``; the solver's own spans
+        lie between the prepare and the stamp."""
+        tracer = self.obs.tracer
+        gen_id, spec, gdir = job.gen_id, job.spec, job.gdir
+        if not job.record_done:
             try:
                 res = solve_streaming_host(
-                    source, self.cfg, q=spec.q, lam0=lam0, mesh=self.mesh,
-                    slots=self.slots, checkpoint_dir=str(ckdir),
-                    resume_from=str(ckdir), screen_init=screen_init,
-                    tracer=self.obs.tracer)
+                    job.source, self.cfg, q=spec.q, lam0=job.lam0,
+                    mesh=self.mesh, slots=self.slots,
+                    checkpoint_dir=str(gdir / "ckpt"),
+                    resume_from=str(gdir / "ckpt"),
+                    screen_init=job.screen_init, tracer=tracer)
             except ChunkFetchError as e:
                 # Failure containment: the solve exhausted its retry
                 # budget. LIVE.json is untouched (readers keep serving
@@ -495,39 +524,10 @@ class RefreshEngine:
                                 for a, err, slept in e.history],
                 })
                 raise
-            record = {
-                "iters": np.int32(res.iters),
-                "warm": np.int32(lam0 is not None),
-                "lam": np.asarray(res.lam),
-                "tau": np.asarray(res.tau),
-                "r": np.asarray(res.r),
-                "primal": np.asarray(res.primal),
-                "dual": np.asarray(res.dual),
-                "fingerprint": source_fingerprint(
-                    source, self.cfg, spec.q,
-                    None if lam0 is None else np.asarray(lam0)),
-            }
-            if res.fin_hist is not None:
-                record["fin_ch"] = np.asarray(res.fin_hist[0])
-                record["fin_gh"] = np.asarray(res.fin_hist[1])
-            if res.screen is not None:
-                # The screening artifacts the NEXT generation's delta
-                # refresh inherits (bool stored as uint8 for the
-                # checkpoint codec), plus the streamed-chunk counts for
-                # observability/benchmarks.
-                record["screen_active"] = np.asarray(
-                    res.screen["active"], np.uint8)
-                record["screen_bmax"] = np.asarray(res.screen["bmax"])
-                record["screen_lam_lo"] = np.asarray(res.screen["lam_lo"])
-                record["screen_streamed"] = np.asarray(
-                    res.screen["streamed_chunks"], np.int64)
+            with tracer.span("refresh.stamp", gen=gen_id):
+                record = self._record(job, res)
             # Publication step 1: the record lands atomically...
-            tracer = self.obs.tracer
-            if tracer.enabled:
-                with tracer.span("refresh.publish", gen=gen_id,
-                                 step="record"):
-                    ckpt.save(gdir / "record", _RECORD_STEP, record)
-            else:
+            with tracer.span("refresh.publish", gen=gen_id, step="record"):
                 ckpt.save(gdir / "record", _RECORD_STEP, record)
         # A re-driven refresh that succeeded clears any failure stamp a
         # previous attempt left: the generation is healthy now.
@@ -536,15 +536,44 @@ class RefreshEngine:
             failed.unlink()
         # ...step 2: the pointer flip makes it live. A crash between the
         # two leaves a complete record that recover()/refresh() re-flips.
-        tracer = self.obs.tracer
-        if tracer.enabled:
-            with tracer.span("refresh.publish", gen=gen_id, step="pointer"):
-                ckpt.write_json(self.root, _POINTER, {"gen": gen_id})
-        else:
+        with tracer.span("refresh.publish", gen=gen_id, step="pointer"):
             ckpt.write_json(self.root, _POINTER, {"gen": gen_id})
-        if self.keep is not None:
-            self.prune()
-        return self.generation(gen_id)
+        with tracer.span("refresh.readback", gen=gen_id):
+            if self.keep is not None:
+                self.prune()
+            return self.generation(gen_id)
+
+    def _record(self, job: _Job, res) -> dict:
+        """The published record of a finished solve: its results read to
+        the host, stamped with the solver's identity hash."""
+        lam0 = job.lam0
+        record = {
+            "iters": np.int32(res.iters),
+            "warm": np.int32(lam0 is not None),
+            "lam": np.asarray(res.lam),
+            "tau": np.asarray(res.tau),
+            "r": np.asarray(res.r),
+            "primal": np.asarray(res.primal),
+            "dual": np.asarray(res.dual),
+            "fingerprint": source_fingerprint(
+                job.source, self.cfg, job.spec.q,
+                None if lam0 is None else np.asarray(lam0)),
+        }
+        if res.fin_hist is not None:
+            record["fin_ch"] = np.asarray(res.fin_hist[0])
+            record["fin_gh"] = np.asarray(res.fin_hist[1])
+        if res.screen is not None:
+            # The screening artifacts the NEXT generation's delta
+            # refresh inherits (bool stored as uint8 for the
+            # checkpoint codec), plus the streamed-chunk counts for
+            # observability/benchmarks.
+            record["screen_active"] = np.asarray(
+                res.screen["active"], np.uint8)
+            record["screen_bmax"] = np.asarray(res.screen["bmax"])
+            record["screen_lam_lo"] = np.asarray(res.screen["lam_lo"])
+            record["screen_streamed"] = np.asarray(
+                res.screen["streamed_chunks"], np.int64)
+        return record
 
     # -- failure surface + generation GC ------------------------------------
 

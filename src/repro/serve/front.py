@@ -284,13 +284,9 @@ class ReplicaServer:
                 live = self.engine.live_gen_id()
                 if live is not None and live != self.svc.generation.gen:
                     gen = self.engine.generation(live)
-                    if self._tracer.enabled:
-                        with self._tracer.span("replica.rebind",
-                                               replica=self.index,
-                                               gen=int(live)):
-                            self.svc.rebind(
-                                self.engine.make_source(gen.spec), gen)
-                    else:
+                    with self._tracer.span("replica.rebind",
+                                           replica=self.index,
+                                           gen=int(live)):
                         self.svc.rebind(
                             self.engine.make_source(gen.spec), gen)
                     self._c_rebinds.inc()
@@ -628,11 +624,8 @@ class Front:
 
     def decide(self, user: int) -> dict:
         req = {"op": "lookup", "user": int(user), "rid": self._rid()}
-        if self._tracer.enabled:
-            with self._tracer.span("front.decide", op="lookup",
-                                   rid=req["rid"], users=1):
-                resp, i = self._route(req)
-        else:
+        with self._tracer.span("front.decide", op="lookup",
+                               rid=req["rid"], users=1):
             resp, i = self._route(req)
         x = unpack_array(resp["x"])
         return {"user": int(user), "x": [int(v) for v in x],
@@ -641,11 +634,8 @@ class Front:
     def decide_batch(self, users) -> dict:
         users = [int(u) for u in users]
         req = {"op": "decide_batch", "users": users, "rid": self._rid()}
-        if self._tracer.enabled:
-            with self._tracer.span("front.decide", op="decide_batch",
-                                   rid=req["rid"], users=len(users)):
-                resp, i = self._route(req)
-        else:
+        with self._tracer.span("front.decide", op="decide_batch",
+                               rid=req["rid"], users=len(users)):
             resp, i = self._route(req)
         return {"users": len(users), "x": resp["x"],
                 "stale": resp["stale"], "gens": resp["gens"], "replica": i}

@@ -1,4 +1,5 @@
-"""CLI launchers: solve.py end-to-end, one dry-run cell, examples."""
+"""CLI launchers: solve.py end-to-end, the refresh journal, one dry-run
+cell, examples."""
 import json
 import os
 import pathlib
@@ -27,6 +28,20 @@ def test_solve_cli():
     assert float(lines["max_violation"]) <= 1e-4
     gap = float(lines["duality_gap"])
     assert 0 <= gap < 0.01 * float(lines["primal"])
+
+
+def test_refresh_cli_obs_journal(tmp_path):
+    """A 2-generation refresh with ``--obs`` leaves a span journal that
+    ``trace_view.py`` reads with the refresh's phases in it."""
+    root = tmp_path / "root"
+    out = _run(["-m", "repro.launch.refresh", "--users", "8192",
+                "--chunk", "512", "--generations", "2", "--lookups", "256",
+                "--obs", "--root", str(root)])
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    view = _run(["tools/trace_view.py", str(root), "--assert-phases",
+                 "refresh.prepare,ckpt.save,refresh.publish"])
+    assert view.returncode == 0, view.stdout + view.stderr
+    assert "all 3 asserted phases present" in view.stdout
 
 
 @pytest.mark.slow

@@ -332,6 +332,94 @@ def test_refresh_bitwise_identical_obs_on_off(tmp_path):
     assert phases.count("refresh.publish") == 2 * len(SCALES)
 
 
+# The spans that tile one refresh, in order (solve.iterate once per
+# iteration, refresh.publish for the record, then for the pointer).
+TOP_PHASES = ("refresh.prepare", "solve.fingerprint", "solve.iterate",
+              "solve.finalize", "refresh.stamp", "refresh.publish",
+              "refresh.readback")
+CKPT_EVERY = 2
+
+
+def _journaled_refreshes(tmp_path, cfg):
+    """Refresh SCALES with a journal and without; returns (generations
+    with it, generations without it, the journal's spans)."""
+    plain = RefreshEngine(tmp_path / "off", SPEC, cfg=cfg)
+    obs = make_obs(tmp_path / "on", role="engine")
+    traced = RefreshEngine(tmp_path / "on", SPEC, cfg=cfg, obs=obs)
+    on = [traced.refresh(budget_scale=s) for s in SCALES]
+    off = [plain.refresh(budget_scale=s) for s in SCALES]
+    obs.close()
+    return on, off, read_trace(obs.tracer.path)
+
+
+def test_refresh_top_level_phases_in_order(tmp_path):
+    gens, _, spans = _journaled_refreshes(tmp_path, CFG)
+    # A span is journaled when it closes, so the top-level spans (which
+    # never nest in one another) appear in the order they ran.
+    top = [s["phase"] for s in spans if s["phase"] in TOP_PHASES]
+    want = []
+    for g in gens:
+        want += (["refresh.prepare", "solve.fingerprint"]
+                 + ["solve.iterate"] * g.iters
+                 + ["solve.finalize", "refresh.stamp", "refresh.publish",
+                    "refresh.publish", "refresh.readback"])
+    assert top == want
+    assert [s["step"] for s in spans if s["phase"] == "refresh.publish"] \
+        == ["record", "pointer"] * len(SCALES)
+
+
+def test_refresh_ckpt_save_spans_match_the_cadence(tmp_path):
+    cfg = CFG.replace(checkpoint_every=CKPT_EVERY)
+    on, off, spans = _journaled_refreshes(tmp_path, cfg)
+    for a, b in zip(on, off):   # saves under a journal change nothing
+        for f in GEN_FIELDS:
+            np.testing.assert_array_equal(np.asarray(getattr(a, f)),
+                                          np.asarray(getattr(b, f)),
+                                          err_msg=f)
+        assert a.iters == b.iters
+    # Per refresh: one save per CKPT_EVERY iterations but the last, the
+    # finalize-entry save, one per CKPT_EVERY finalize columns but the
+    # last column.
+    want = sum((g.iters - 1) // CKPT_EVERY + 1 + (CHUNKS - 1) // CKPT_EVERY
+               for g in on)
+    ckpt_spans = [s["phase"] for s in spans if s["phase"].startswith("ckpt.")]
+    # Each save holds one gather and one write, closed before it closes.
+    assert ckpt_spans == ["ckpt.gather", "ckpt.write", "ckpt.save"] * want
+    saves = [s for s in spans if s["phase"] == "ckpt.save"]
+    inner = [s for s in spans if s["phase"] in ("ckpt.gather", "ckpt.write")]
+    for i, save in enumerate(saves):
+        for child in inner[2 * i:2 * i + 2]:
+            assert child["t"] >= save["t"] - 1e-3
+            assert child["dur_s"] <= save["dur_s"]
+
+
+def test_tracer_span_reaches_the_profiler_host_plane(tmp_path):
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    log_dir = str(tmp_path / "profile")
+    tr = Tracer(trace_path(tmp_path, "prof"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        with tr.span("test.profiled", step=1):
+            jnp.arange(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    tr.close()
+    path = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    names = {e.name for p in host for line in p.lines for e in line.events}
+    assert "test.profiled" in names
+    # The journal keeps its format: one record with the span's attrs.
+    (rec,) = read_trace(tr.path)
+    assert rec["phase"] == "test.profiled" and rec["step"] == 1
+    assert set(rec) == {"phase", "t", "dur_s", "pid", "step"}
+
+
 # ---------------------------------------------------------------------------
 # /metrics over the wire: replica RPC, front aggregation, rid correlation.
 # ---------------------------------------------------------------------------
