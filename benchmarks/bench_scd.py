@@ -27,6 +27,7 @@ import jax.numpy as jnp  # noqa: E402
 from benchmarks.common import timeit  # noqa: E402
 from repro.core.bucketing import make_edges  # noqa: E402
 from repro.kernels import ops  # noqa: E402
+from repro.kernels.scd_fused import LANE_TILE  # noqa: E402
 
 # Per-device user shards at production scale (a billion users over a pod
 # is ~1e4-1e5 per core). Below ~4k rows the interpret-mode dispatch
@@ -44,9 +45,9 @@ def _unfused(p, b, lam, edges, q, tile):
     return hist, jnp.max(v1, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("q", "tile"))
-def _fused(p, b, lam, edges, q, tile):
-    return ops.scd_fused_hist(p, b, lam, edges, q, tile_n=tile)
+@functools.partial(jax.jit, static_argnames=("q",))
+def _fused(p, b, lam, edges, q):
+    return ops.scd_fused_hist(p, b, lam, edges, q)
 
 
 def bench_point(n, k, q=2, half=24, seed=0, samples=16):
@@ -61,12 +62,12 @@ def bench_point(n, k, q=2, half=24, seed=0, samples=16):
     # estimator, and interleaving keeps scheduler/load drift on a shared
     # host from biasing whichever variant runs second.
     jax.block_until_ready(_unfused(p, b, lam, edges, q, tile))
-    jax.block_until_ready(_fused(p, b, lam, edges, q, tile))
+    jax.block_until_ready(_fused(p, b, lam, edges, q))
     ts_u, ts_f = [], []
     for _ in range(samples):
         ts_u.append(timeit(_unfused, p, b, lam, edges, q, tile,
                            warmup=0, iters=1))
-        ts_f.append(timeit(_fused, p, b, lam, edges, q, tile,
+        ts_f.append(timeit(_fused, p, b, lam, edges, q,
                            warmup=0, iters=1))
     t_unfused = min(ts_u)
     t_fused = min(ts_f)
@@ -75,6 +76,7 @@ def bench_point(n, k, q=2, half=24, seed=0, samples=16):
         "k": k,
         "q": q,
         "tile": tile,
+        "lane_tile": min(LANE_TILE, n),
         "unfused_s": t_unfused,
         "fused_s": t_fused,
         "speedup": t_unfused / t_fused,

@@ -14,7 +14,7 @@ One chip:
 (b) resident solve at n = 1e7 through ``repro.launch.solve.run``, once on
     the Pallas kernel path and once on the jnp path; the kernel
     program's HLO must hold ``tpu_custom_call`` (compiled, not
-    interpreted), and the two runs agree;
+    interpreted) and no relayout copy of p or b, and the two runs agree;
 (c) out-of-core streaming solve of ``table1`` at its full n = 1e8 through
     ``repro.launch.solve.run_streaming`` (chunks generated on device);
 (d) two refresh generations at n = 4e6 through
@@ -23,8 +23,10 @@ One chip:
 (e) plain reference: (b)'s kernel-path decisions re-scored in float64
     NumPy, and a 1e6-user instance solved on the CPU backend against the
     chip;
-(f) precision: the kernels' one-hot histogram contractions on operands
-    that bf16 rounding moves by 2^-10, against float64.
+(f) precision: the kernels' histogram sums (the VPU bucket sums of
+    ``bucket_hist`` and ``scd_fused_hist``, the finalize kernel's one-hot
+    contraction) on operands that bf16 rounding moves by 2^-10, against
+    float64.
 
 Four chips (``--chips 4``): the host-fed sharded streaming solve with 4
 slots on a 4-device mesh against the same solve on one device (bitwise),
@@ -129,12 +131,11 @@ def assert_compiled_kernels(hlo, what):
 
 
 def relayout_report(hlo, n):
-    """Where the kernel path lays p and b out again for the Pallas call.
+    """Where the kernel path lays p and b out again for a Pallas call.
 
-    The entry arguments keep users on lanes; the kernels read (tile, K)
-    row-major blocks, so XLA copies p and b into a K-on-lanes layout.
-    Reports whether those copies sit in the entry computation (once per
-    solve) or inside a loop body (every iteration).
+    The entry arguments keep users on lanes, and so do the blocks of
+    the fused kernel, so the solve should hold no such copy. Reports the
+    computations that copy p or b into a K-on-lanes (n, K) layout.
     """
     comp, where = None, []
     for line in hlo.splitlines():
@@ -177,8 +178,8 @@ def phase_resident(dev, n=N_RESIDENT, max_iters=MAX_ITERS):
         if kernels:
             assert_compiled_kernels(hlo, "resident kernel solve")
             where = relayout_report(hlo, n)
-            log(f"    kernel relayout copies of p, b: {len(where)} in "
-                f"{sorted(set(where))}")
+            check(not where, f"kernel relayout copies of p, b: "
+                  f"{len(where)} in {sorted(set(where))} (none)")
         else:
             check("tpu_custom_call" not in hlo,
                   "resident jnp solve: no Pallas call in its HLO")
@@ -354,8 +355,8 @@ def phase_precision(dev, n=N_PRECISION):
             np.add.at(out[k], idx, mass[:, k])
         return out
 
-    # bucket_hist (the K-batched einsum shared with scd_fused_hist): v1
-    # spread over the ladder so many buckets fill.
+    # bucket_hist (hist_block's VPU bucket sums, shared with
+    # scd_fused_hist): v1 spread over the ladder so many buckets fill.
     v1 = (-0.99 + 1.98 * ((rows[:, None] * 7 + np.arange(K)) % 97) / 97
           ).astype(np.float32)
     v2 = np.full((n, K), v, np.float32)

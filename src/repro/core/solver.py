@@ -172,7 +172,7 @@ def _scd_step_fused(kp, lam, q, keep, scale, cfg, axis):
     from ..kernels import ops as kops
     edges = make_edges(lam, cfg.bucket_delta, cfg.bucket_growth, cfg.bucket_half)
     hist, top = kops.scd_fused_hist(kp.p, kp.b, lam, edges, q,
-                                    tile_n=_kernel_tile(cfg, kp.p.shape[0]))
+                                    tile_n=cfg.kernel_tile)
     hist = _psum(hist * (keep * scale), axis)
     top = jax.lax.pmax(top, axis) if axis is not None else top
     return threshold_from_hist(hist, edges, kp.budgets, top)
@@ -223,7 +223,7 @@ def scd_chunk_accumulate(p_c, b_c, lam, edges, q, cfg, hist, top,
     if cfg.use_kernels:
         from ..kernels import ops as kops
         return kops.scd_fused_hist(p_c, b_c, lam, edges, q,
-                                   tile_n=_kernel_tile(cfg, p_c.shape[0]),
+                                   tile_n=cfg.kernel_tile,
                                    hist_init=hist, top_init=top)
     v1, v2 = candidates_sparse(p_c, b_c, lam, q)
     if keep is not None:

@@ -12,7 +12,10 @@ scd_fused_hist  — scd_candidates + bucket_hist in one streaming pass: the
                   contract: core/solver.py).
 
 All wrappers take a user-axis tile (``pick_tile`` chooses; ragged shards
-are padded with inert rows inside the wrapper). They compile on a TPU and
+are padded with inert rows inside the wrapper). ``scd_fused_hist`` puts
+users on lanes, in (K, tile) blocks: its tile comes from
+``scd_fused.LANE_TILE`` and it masks a ragged last block inside the
+kernel. They compile on a TPU and
 run under the Pallas interpreter on the CPU; any other backend raises
 (``_util.resolve_interpret``). ``use_pallas=False`` dispatches to the
 pure-jnp oracles in ``ref``.

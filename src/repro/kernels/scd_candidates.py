@@ -20,39 +20,44 @@ from jax.experimental import pallas as pl
 from ._util import pad_rows, resolve_interpret
 
 
-def _order_stats(ap, q):
-    """(n,K) -> (q_th (n,1), q1_th (n,1)) largest values (with multiplicity)."""
-    n, k = ap.shape
+def _order_stats(ap, q, axis):
+    """Q-th / (Q+1)-th largest of ``ap`` along the knapsack ``axis``
+    (with multiplicity), each kept as a size-1 axis."""
+    k = ap.shape[axis]
     neg_inf = jnp.asarray(-jnp.inf, ap.dtype)
     work = ap
-    q_th = jnp.full((n, 1), jnp.inf, ap.dtype)
-    q1_th = jnp.full((n, 1), jnp.inf, ap.dtype)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n, k), 1)
+    q_th = q1_th = jnp.full(
+        ap.shape[:axis] + (1,) + ap.shape[axis + 1:], jnp.inf, ap.dtype)
+    idx = jax.lax.broadcasted_iota(jnp.int32, ap.shape, axis)
     for i in range(q + 1):
-        m = jnp.max(work, axis=1, keepdims=True)
+        m = jnp.max(work, axis=axis, keepdims=True)
         if i == q - 1:
             q_th = m
         if i == q:
             q1_th = m
         is_max = work == m
-        pick_idx = jnp.min(jnp.where(is_max, idx, k), axis=1, keepdims=True)
+        pick_idx = jnp.min(jnp.where(is_max, idx, k), axis=axis,
+                           keepdims=True)
         work = jnp.where(idx == pick_idx, neg_inf, work)
     return q_th, q1_th
 
 
-def candidates_block(p, b, lam, q):
+def candidates_block(p, b, lam, q, axis=1):
     """Alg 5 candidate pairs (v1, v2) for one VMEM-resident block.
 
-    p, b: (tile_n, K); lam: (1, K). Invalid candidates are encoded as
-    v1 = -1, v2 = 0. Shared by this kernel and the fused map+reduce
-    kernel (scd_fused.py) so the tie-sensitive semantics exist once.
+    ``axis`` is the knapsack axis, fixed by the calling kernel's block
+    layout: p, b are (tile_n, K) with lam (1, K) for ``axis=1`` (this
+    kernel), or (K, tile) with lam (K, 1) for ``axis=0`` (users on lanes,
+    the fused kernel of scd_fused.py). Invalid candidates are encoded as
+    v1 = -1, v2 = 0. Both kernels call this one body, so the
+    tie-sensitive semantics exist once.
     """
     ap = jnp.maximum(p - lam * b, 0.0)
-    k = p.shape[-1]
+    k = p.shape[axis]
     if q >= k:
         pbar = jnp.zeros_like(ap)
     else:
-        q_th, q1_th = _order_stats(ap, q)
+        q_th, q1_th = _order_stats(ap, q, axis)
         in_top = ap >= q_th
         pbar = jnp.where(in_top, q1_th, q_th)
     valid = (p > pbar) & (b > 0)

@@ -5,7 +5,7 @@ adjusted profits ``ap = max(p - lam*b, 0)``, the two Alg-5 order
 statistics (Q-th / (Q+1)-th largest per user), the candidate pairs
 ``v1 = (p - pbar)/b``, ``v2 = b``, the §5.2 binning of ``v1`` against the
 per-knapsack edge ladder, and the running per-knapsack max of ``v1`` —
-accumulating straight into the (K, E+1) histogram and (1, K) top blocks
+accumulating straight into the (K, E+1) histogram and (K, 1) top blocks
 that live in VMEM across the whole grid.
 
 This is the paper's communication-compression argument applied one level
@@ -15,10 +15,18 @@ histogram leaves the core. The unfused pair (scd_candidates ->
 bucket_hist) writes and re-reads the full (n, K) ``v1``/``v2`` arrays
 through HBM every iteration — 4 O(n*K) transfers this kernel deletes.
 
-Order statistics use the same Q+1 sequential masked-max passes as
-scd_candidates.py (quick-select does not vectorise on the VPU); binning
-is the same branch-free edge-ladder compare + one-hot MXU contraction as
-bucket_hist.py. Both unfused kernels remain the parity oracle.
+Users on lanes: :func:`scd_fused_hist` takes ``(n, K)`` arguments and
+hands the kernel their logical transpose, in ``(K, tile)`` blocks with
+``tile`` users along the 128-wide lane axis and the K knapsacks on
+sublanes. (With K = 10 a ``(tile_n, K)`` block would fill 10 of 128
+lanes.) XLA keeps an ``(n, K)`` array with small K users-minor on the
+TPU, so on a resident array the transpose is a bitcast, not a copy.
+The kernel runs the same axis-generic bodies as the two standalone
+kernels — :func:`candidates_block` and :func:`hist_block` with the
+knapsack axis 0: the order statistics reduce over sublanes, each edge of
+the ladder is a (K, 1) column broadcast along the lanes, and each
+bucket's mass is an f32 sum over the lanes on the VPU. The standalone
+kernels stay ``(tile_n, K)`` and remain the parity oracle.
 """
 from __future__ import annotations
 
@@ -33,14 +41,31 @@ from .adjusted_topc import _topq_mask
 from .bucket_hist import hist_block
 from .scd_candidates import candidates_block
 
+# Users per grid step of scd_fused_hist, from a sweep of 1024-16384 on a
+# TPU v5e at n = 1e7, K = 10 (PERF.md §6: 2048 and 4096 tie, 8192 and
+# 16384 are ~9% slower); a power of two, so it divides the host-fed
+# chunk of 65536 users.
+LANE_TILE = 4096
+
 
 def _kernel(p_ref, b_ref, lam_ref, edges_ref, hist0_ref, top0_ref,
-            hist_ref, top_ref, *, q):
+            hist_ref, top_ref, *, q, n):
     # Alg 5 map, then the §5.2 binning — the same shared blocks the two
-    # standalone kernels run, but v1/v2 stay in VMEM between them.
-    v1, v2 = candidates_block(p_ref[...], b_ref[...], lam_ref[...], q)
-    tile_hist = hist_block(v1, v2, edges_ref[...])        # (K, E+1)
-    tile_top = jnp.max(v1, axis=0, keepdims=True)         # (1, K)
+    # standalone kernels run, here on (K, tile) blocks (knapsack axis 0),
+    # with v1/v2 kept in VMEM between them.
+    v1, v2 = candidates_block(p_ref[...], b_ref[...], lam_ref[...], q,
+                              axis=0)
+    tile = v1.shape[1]
+    if n % tile:
+        # The last block overhangs the n users; what it reads there is
+        # undefined. Those lanes become invalid candidates (v1 = -1,
+        # v2 = 0): zero mass, and they never raise the top.
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        real = pl.program_id(0) * tile + lane < n
+        v1 = jnp.where(real, v1, -1.0)
+        v2 = jnp.where(real, v2, 0.0)
+    tile_hist = hist_block(v1, v2, edges_ref[...], axis=0)   # (K, E+1)
+    tile_top = jnp.max(v1, axis=1, keepdims=True)             # (K, 1)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -52,7 +77,7 @@ def _kernel(p_ref, b_ref, lam_ref, edges_ref, hist0_ref, top0_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("q", "tile_n", "interpret"))
-def scd_fused_hist(p, b, lam, edges, q, tile_n=512, interpret=None,
+def scd_fused_hist(p, b, lam, edges, q, tile_n=None, interpret=None,
                    hist_init=None, top_init=None):
     """Fused Alg-5 map + §5.2 histogram. No (n, K) intermediate in HBM.
 
@@ -60,6 +85,11 @@ def scd_fused_hist(p, b, lam, edges, q, tile_n=512, interpret=None,
     (hist (K, E+1) f32, top (K,) p.dtype) — exactly
     ``bucket_hist(*scd_candidates(p, b, lam, q), edges)`` and
     ``max(v1, axis=0)``, with v1/v2 never materialised off-chip.
+
+    The kernel sees users on lanes: ``tile_n`` users per grid step in
+    (K, tile_n) blocks of ``p.T`` and ``b.T``; ``None`` takes
+    ``LANE_TILE``, or the whole shard when it is smaller. No tile has to
+    divide n. On the TPU a tile below n must be a multiple of 128.
 
     ``hist_init`` (K, E+1) / ``top_init`` (K,) seed the VMEM accumulators
     (defaults: zeros / -inf, the unseeded behaviour). The out-of-core
@@ -72,47 +102,45 @@ def scd_fused_hist(p, b, lam, edges, q, tile_n=512, interpret=None,
     tile_n; see core/solver.py). The seed inputs are aliased to the
     outputs so the carried accumulator is updated in place on TPU.
 
-    Ragged n is handled by padding the user axis with (p=0, b=0) rows:
-    those are invalid candidates (v1=-1, v2=0), contributing zero mass
-    and never raising the top (real v1 is -1 or positive).
+    Ragged n is masked inside the kernel from the true n: the lanes of
+    the last block past n are invalid candidates (v1 = -1, v2 = 0),
+    contributing zero mass and never raising the top (real v1 is -1 or
+    positive), exactly as the inert (p = 0, b = 0) rows a chunked caller
+    pads with. Nothing is padded or copied per call.
     """
     n, k = p.shape
     e = edges.shape[-1]
     interpret = resolve_interpret(interpret)
-    tile_n = min(tile_n, n)
-    pad = -n % tile_n
-    p = pad_rows(p, pad)
-    b = pad_rows(b, pad)
-    grid = ((n + pad) // tile_n,)
-    lam2 = lam.reshape(1, k).astype(p.dtype)
+    tile_n = min(tile_n or LANE_TILE, n)
+    grid = (pl.cdiv(n, tile_n),)
     if hist_init is None:
         hist_init = jnp.zeros((k, e + 1), jnp.float32)
     if top_init is None:
         top_init = jnp.full((k,), -jnp.inf, p.dtype)
     hist, top = pl.pallas_call(
-        functools.partial(_kernel, q=q),
+        functools.partial(_kernel, q=q, n=n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            pl.BlockSpec((k, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((k, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
             pl.BlockSpec((k, e), lambda i: (0, 0)),
             pl.BlockSpec((k, e + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((k, e + 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            pl.BlockSpec((k, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k, e + 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, k), p.dtype),
+            jax.ShapeDtypeStruct((k, 1), p.dtype),
         ],
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(p, b, lam2, edges.astype(p.dtype),
-      hist_init.astype(jnp.float32), top_init.reshape(1, k).astype(p.dtype))
-    return hist, top[0]
+    )(p.T, b.T, lam.reshape(k, 1).astype(p.dtype), edges.astype(p.dtype),
+      hist_init.astype(jnp.float32), top_init.reshape(k, 1).astype(p.dtype))
+    return hist, top[:, 0]
 
 
 def finalize_block(p, b, lam, q):

@@ -1,11 +1,15 @@
 """Fused SCD map+reduce kernel (scd_fused_hist) vs the unfused paths.
 
-The fused kernel must be bit-compatible (up to float accumulation order)
-with the composition it replaces — ``bucket_histogram(candidates_sparse)``
-on the jnp side and ``bucket_hist(scd_candidates(...))`` on the kernel
-side — including tie cases exactly on bucket edges, all-invalid tiles and
-the ragged-n padding path. The solve driver's while_loop fast path must
-reproduce the scan path's trajectory exactly.
+The fused kernel puts users on lanes ((K, tile) blocks); the unfused
+kernels it is checked against keep (tile_n, K) blocks. It must be
+bit-compatible (up to float accumulation order) with the composition it
+replaces — ``bucket_histogram(candidates_sparse)`` on the jnp side and
+``bucket_hist(scd_candidates(...))`` on the kernel side — including tie
+cases exactly on bucket edges, all-invalid tiles, K that fills sublanes
+partly or spills past 16, and a ragged last block masked inside the
+kernel. Chunked calls carrying the seeded accumulators must equal one
+call bit for bit at a pinned lane tile. The solve driver's while_loop
+fast path must reproduce the scan path's trajectory exactly.
 """
 import jax
 import jax.numpy as jnp
@@ -17,10 +21,14 @@ from repro.core.bucketing import bucket_histogram, make_edges
 from repro.core.instances import shard_key, sparse_instance
 from repro.core.sparse_scd import candidates_sparse
 from repro.kernels import ops, ref
+from repro.kernels.scd_fused import LANE_TILE
 
 jax.config.update("jax_platform_name", "cpu")
 
-SHAPES = [(128, 8), (512, 16), (384, 10), (383, 8), (1021, 8), (7, 4)]
+# K = 3, 10 and 17 fill the sublanes of a (K, tile) block partly, to
+# 10 of 16, and past 16.
+SHAPES = [(128, 8), (512, 16), (384, 10), (383, 8), (1021, 8), (7, 4),
+          (300, 3), (257, 17)]
 
 
 def _inst(n, k, dtype=jnp.float32, seed=0):
@@ -50,14 +58,16 @@ def test_fused_matches_unfused_jnp_composition(shape, q):
     np.testing.assert_allclose(float(h_f.sum()), float(v2.sum()), rtol=1e-5)
 
 
-@pytest.mark.parametrize("shape", [(256, 8), (383, 16)])
-def test_fused_matches_unfused_kernel_composition(shape):
-    """Parity vs the two-kernel path it replaces in the solver."""
+@pytest.mark.parametrize("tile", [128, 256, None])
+@pytest.mark.parametrize("shape", [(256, 8), (383, 16), (1000, 17)])
+def test_fused_matches_unfused_kernel_composition(shape, tile):
+    """Parity vs the two-kernel path it replaces in the solver, at lane
+    tiles of 128, 256 and the default."""
     n, k = shape
     q = 2
     p, b, lam = _inst(n, k, seed=5)
     edges = make_edges(lam, 1e-4, 1.6, 24)
-    h_f, top_f = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=128,
+    h_f, top_f = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=tile,
                                     interpret=True)
     v1, v2 = ops.scd_candidates(p, b, lam, q, tile_n=128, interpret=True)
     h_u = ops.bucket_hist(v1, v2, edges, tile_n=128, interpret=True)
@@ -67,21 +77,22 @@ def test_fused_matches_unfused_kernel_composition(shape):
                                np.asarray(jnp.max(v1, axis=0)), rtol=1e-6)
 
 
-def test_fused_ties_exactly_on_bucket_edges():
+@pytest.mark.parametrize("k,reps,tile", [(4, 1, 4), (10, 50, 128)])
+def test_fused_ties_exactly_on_bucket_edges(k, reps, tile):
     """Candidates landing exactly on an edge bin identically in all paths.
 
     q >= K makes pbar = 0 so v1 = p/b = p (b = 1): rows are placed
     exactly on the edge ladder. searchsorted-left convention: a candidate
-    at edges[j] belongs to bucket j, not j+1.
+    at edges[j] belongs to bucket j, not j+1. The second case spreads
+    the rows over three (K, 128) lane blocks, the last one ragged.
     """
-    k = 4
     edges = jnp.tile(jnp.array([[0.5, 1.0, 1.5]]), (k, 1))
-    vals = jnp.array([0.5, 1.0, 1.5, 0.25, 1.75, 1.0])
+    vals = jnp.tile(jnp.array([0.5, 1.0, 1.5, 0.25, 1.75, 1.0]), reps)
     p = jnp.tile(vals[:, None], (1, k))
     b = jnp.ones_like(p)
     lam = jnp.zeros((k,))
     q = k  # local constraint never binds -> v1 = p
-    h_f, top_f = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=4,
+    h_f, top_f = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=tile,
                                     interpret=True)
     v1, v2 = candidates_sparse(p, b, lam, q)
     h_u = bucket_histogram(v1, v2, edges)
@@ -90,7 +101,7 @@ def test_fused_ties_exactly_on_bucket_edges():
     np.testing.assert_array_equal(np.asarray(h_f), np.asarray(h_r))
     # explicit tie placement: bucket j = (edges[j-1], edges[j]]
     np.testing.assert_array_equal(np.asarray(h_f[0]),
-                                  np.array([2.0, 2.0, 1.0, 1.0]))
+                                  reps * np.array([2.0, 2.0, 1.0, 1.0]))
     np.testing.assert_allclose(np.asarray(top_f), np.full(k, 1.75), rtol=0)
 
 
@@ -123,6 +134,69 @@ def test_fused_ragged_padding_is_invisible():
                                rtol=1e-6)
     v1, v2 = candidates_sparse(p, b, lam, q)
     np.testing.assert_allclose(float(h_rag.sum()), float(v2.sum()), rtol=1e-5)
+
+
+@pytest.mark.parametrize("tile", [128, None])
+def test_fused_ragged_mask_equals_exact_tiles(tile, monkeypatch):
+    """n = 10037, which no lane tile divides: the kernel's in-kernel mask
+    of the last block gives bit for bit what the same rows padded with
+    inert (p = 0, b = 0) users to a tile multiple give, and every unit
+    of candidate mass lands in a bucket.
+
+    On the TPU the lanes of the last block past n hold whatever its VMEM
+    buffer held before. The interpreter fills them with NaN, which would
+    be inert by itself, so the test plants valid-looking users there.
+    """
+    from jax._src.pallas import primitives as pallas_primitives
+    nan_fill = pallas_primitives.uninitialized_value
+
+    def stale_fill(shape, dtype):
+        if jnp.issubdtype(dtype, jnp.floating):
+            return jnp.full(shape, 0.75, dtype)
+        return nan_fill(shape, dtype)
+
+    monkeypatch.setattr(pallas_primitives, "uninitialized_value", stale_fill)
+    n, k, q = 10037, 10, 1
+    p, b, lam = _inst(n, k, seed=11)
+    edges = make_edges(lam, 1e-4, 1.6, 24)
+    t = tile or min(LANE_TILE, n)
+    pad = -n % t
+    h_rag, top_rag = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=tile,
+                                        interpret=True)
+    h_pad, top_pad = ops.scd_fused_hist(
+        jnp.pad(p, ((0, pad), (0, 0))), jnp.pad(b, ((0, pad), (0, 0))),
+        lam, edges, q, tile_n=t, interpret=True)
+    np.testing.assert_array_equal(np.asarray(h_rag), np.asarray(h_pad))
+    np.testing.assert_array_equal(np.asarray(top_rag), np.asarray(top_pad))
+    v1, v2 = candidates_sparse(p, b, lam, q)
+    np.testing.assert_allclose(float(h_rag.sum()), float(v2.sum()), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(top_rag),
+                               np.asarray(jnp.max(v1, axis=0)), rtol=0)
+
+
+@pytest.mark.parametrize("tile", [128, 256])
+def test_fused_chunked_bitwise_at_pinned_lane_tile(tile):
+    """Chunks of 2 * tile users carried through the seeded accumulators
+    give bit for bit the one call over all users at the same lane tile,
+    with a ragged final chunk padded by inert users as the chunked
+    solvers pad it."""
+    n, k, q = 1021, 10, 2
+    p, b, lam = _inst(n, k, seed=13)
+    edges = make_edges(lam, 1e-4, 1.6, 24)
+    h_one, top_one = ops.scd_fused_hist(p, b, lam, edges, q, tile_n=tile,
+                                        interpret=True)
+    chunk = 2 * tile
+    pad = -n % chunk
+    pc = jnp.pad(p, ((0, pad), (0, 0))).reshape(-1, chunk, k)
+    bc = jnp.pad(b, ((0, pad), (0, 0))).reshape(-1, chunk, k)
+    hist = jnp.zeros_like(h_one)
+    top = jnp.full((k,), -jnp.inf)
+    for i in range(pc.shape[0]):
+        hist, top = ops.scd_fused_hist(pc[i], bc[i], lam, edges, q,
+                                       tile_n=tile, interpret=True,
+                                       hist_init=hist, top_init=top)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(h_one))
+    np.testing.assert_array_equal(np.asarray(top), np.asarray(top_one))
 
 
 @pytest.mark.parametrize("use_kernels", [False, True])
