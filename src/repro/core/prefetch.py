@@ -1007,8 +1007,11 @@ class _ShardedRuntime:
         with self.tracer.span("ingest.fetch", col=int(j)):
             ps, bs = self._fetch_cols(j, screen, dt)
         with self.tracer.span("ingest.h2d", col=int(j)):
-            pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
-            bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
+            # The host copy of the S slots' chunks into one column; the
+            # rest of ``ingest.h2d`` is the sharded upload alone.
+            with self.tracer.span("ingest.stack", col=int(j)):
+                pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
+                bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
             return (jax.device_put(pb, self.slot_sh),
                     jax.device_put(bb, self.slot_sh))
 
@@ -1053,7 +1056,9 @@ class _ShardedRuntime:
         if cfg.algo == "dd":
             r0 = jax.device_put(np.zeros((S, k), dt), self.slot_sh)
             (r,) = self._epoch_cols(st["dd_step"], (r0,), (lam,))
-            return st["dd_combine"](np.asarray(r), lam, dprev, self.budgets)
+            with self.tracer.span("slots.combine"):
+                return st["dd_combine"](np.asarray(r), lam, dprev,
+                                        self.budgets)
         edges = make_edges(lam, cfg.bucket_delta, cfg.bucket_growth,
                            cfg.bucket_half)
         if self.scr is not None:
@@ -1065,8 +1070,9 @@ class _ShardedRuntime:
         top0 = jax.device_put(np.full((S, k), -np.inf, dt), self.slot_sh)
         hist, top = self._epoch_cols(st["scd_step"], (hist0, top0),
                                      (lam, edges, self.keep))
-        return st["scd_combine"](np.asarray(hist), np.asarray(top), lam,
-                                 dprev, self.budgets, edges)
+        with self.tracer.span("slots.combine"):
+            return st["scd_combine"](np.asarray(hist), np.asarray(top), lam,
+                                     dprev, self.budgets, edges)
 
     def _iter_epoch_screened(self, lam, dprev, edges):
         """Screened SCD epoch: retired slots feed zeros, columns with no
@@ -1089,8 +1095,10 @@ class _ShardedRuntime:
             hist, top = self._epoch_cols(st["scd_step"], (hist0, top0),
                                          (lam, edges, self.keep),
                                          indices=indices, screen=screen)
-            return st["scd_combine_scr"](np.asarray(hist), np.asarray(top),
-                                         lam, dprev, self.budgets, edges)
+            with self.tracer.span("slots.combine"):
+                return st["scd_combine_scr"](np.asarray(hist),
+                                             np.asarray(top), lam, dprev,
+                                             self.budgets, edges)
 
         lam_n, d_n, moved, trusted = run(indices=cols, screen=True)
         scr.record_streamed(streamed)
@@ -1107,8 +1115,9 @@ class _ShardedRuntime:
                              self.cfg.dtype)
         carry = tuple(jax.device_put(a, self.slot_sh) for a in init)
         out = self._epoch_cols(self.st["metrics_step"], carry, (lam,))
-        return self.st["metrics_combine"](
-            tuple(np.asarray(a) for a in out[:3]), lam, self.budgets)
+        with self.tracer.span("slots.combine"):
+            return self.st["metrics_combine"](
+                tuple(np.asarray(a) for a in out[:3]), lam, self.budgets)
 
     def fin_init(self):
         fin = _fin_zeros_np(self.slots, self.source.k,
@@ -1121,9 +1130,10 @@ class _ShardedRuntime:
                                 start=start, on_col=on_col)
 
     def fin_result(self, carry, lam, iters):
-        vals = tuple(np.asarray(a) for a in carry)
-        r, primal, dual, tau, ch, gh = self.st["fin_combine"](
-            vals, lam, self.budgets)
+        with self.tracer.span("slots.combine"):
+            vals = tuple(np.asarray(a) for a in carry)
+            r, primal, dual, tau, ch, gh = self.st["fin_combine"](
+                vals, lam, self.budgets)
         fin_hist = (ch, gh) if self.cfg.postprocess else None
         return StreamResult(lam, jnp.int32(iters), r, primal, dual, tau,
                             None, fin_hist)
@@ -1197,7 +1207,10 @@ def solve_streaming_host(source: HostChunkSource,
     (finalize-entry save, the finalize pass and its result). Inside
     them: ``ckpt.save`` with ``ckpt.gather`` and ``ckpt.write`` per
     resume-state save, ``ingest.fetch`` / ``ingest.h2d`` and
-    ``screen.skip``. Tracing is *not* a ``SolverConfig`` field:
+    ``screen.skip``; on the sharded runtime also ``ingest.stack`` (the
+    host copy of a column's slot chunks, inside its ``ingest.h2d``) and
+    ``slots.combine`` (each epoch's read-back and fixed-order fold of
+    the slot partials). Tracing is *not* a ``SolverConfig`` field:
     it never enters the resume fingerprint, and because spans bracket
     only host Python (never a value inside a jitted program), a traced
     solve is bitwise identical to an untraced one (``tests/test_obs.py``

@@ -311,6 +311,85 @@ def test_sharded_solve_bitwise_identical_obs_on_off(tmp_path):
     assert {"solve.iterate", "solve.finalize", "ingest.fetch"} <= phases
 
 
+# The sharded runtime's own host phases, on a mesh of four host devices.
+# The devices are forced the way benchmarks/chip/tests/conftest.py forces
+# them, in a subprocess, so they never leak into this process's JAX.
+_SHARDED_SPANS_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = " ".join(
+    [f for f in os.environ.get("XLA_FLAGS", "").split()
+     if not f.startswith("--xla_force_host_platform_device_count")]
+    + ["--xla_force_host_platform_device_count=4"])
+import jax
+import numpy as np
+from repro.core import SolverConfig
+from repro.core.prefetch import solve_streaming_host
+from repro.data.synth import sparse_host_chunk_source
+from repro.obs import Tracer, trace_path
+
+root = sys.argv[1]
+assert len(jax.devices()) == 4, jax.devices()
+mesh = jax.make_mesh((4,), ("slots",))
+cfg = SolverConfig(reduce="bucketed", max_iters=20, checkpoint_every=0)
+# 1000 users in chunks of 128: 8 chunks, the last one ragged, so the
+# last of the 4 slots ends on the ragged chunk.
+src = lambda: sparse_host_chunk_source(3, 1000, 6, 128, q=2, tightness=0.3)
+base = solve_streaming_host(src(), cfg, q=2, mesh=mesh, slots=4)
+with Tracer(trace_path(root, "shard4")) as tr:
+    traced = solve_streaming_host(src(), cfg, q=2, mesh=mesh, slots=4,
+                                  tracer=tr)
+for f in ("lam", "iters", "r", "primal", "dual", "tau"):
+    np.testing.assert_array_equal(np.asarray(getattr(base, f)),
+                                  np.asarray(getattr(traced, f)), err_msg=f)
+print(tr.path)
+print(int(traced.iters))
+"""
+
+
+def test_sharded_runtime_spans_stack_and_combine(tmp_path):
+    """On a 4-device mesh with 4 slots, every column's ``ingest.h2d``
+    holds one ``ingest.stack``, and every iteration epoch and the
+    finalize each close one ``slots.combine`` inside their top-level
+    span; the traced solve is bitwise the untraced one."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _SHARDED_SPANS_SCRIPT, str(tmp_path)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+    path, iters = out.stdout.split()[-2:]
+    iters = int(iters)
+    spans = read_trace(path)
+    cols = 2                                # ceil(8 chunks / 4 slots)
+
+    h2d = [s for s in spans if s["phase"] == "ingest.h2d"]
+    stack = [s for s in spans if s["phase"] == "ingest.stack"]
+    assert len(h2d) == len(stack) == (iters + 1) * cols
+    # A span is journaled when it closes: each stack just before its h2d.
+    for inner, outer in zip(stack, h2d):
+        assert inner["col"] == outer["col"]
+        assert inner["t"] >= outer["t"] - 1e-3
+        assert inner["t"] + inner["dur_s"] <= \
+            outer["t"] + outer["dur_s"] + 1e-3
+
+    top = [s["phase"] for s in spans if s["phase"] in
+           ("slots.combine", "solve.iterate", "solve.finalize")]
+    assert top == (["slots.combine", "solve.iterate"] * iters
+                   + ["slots.combine", "solve.finalize"])
+
+
+def test_single_runtime_opens_no_sharded_spans(tmp_path):
+    """The one-device runtime neither stacks slot columns nor combines
+    slot partials, so it opens neither span."""
+    cfg = SolverConfig(reduce="bucketed", max_iters=20, checkpoint_every=0)
+    with Tracer(trace_path(tmp_path, "single")) as tr:
+        solve_streaming_host(_source(), cfg, q=2, tracer=tr)
+    phases = {s["phase"] for s in read_trace(tr.path)}
+    assert {"solve.iterate", "ingest.h2d"} <= phases
+    assert not phases & {"ingest.stack", "slots.combine"}
+
+
 def test_refresh_bitwise_identical_obs_on_off(tmp_path):
     plain = RefreshEngine(tmp_path / "off", SPEC, cfg=CFG)
     obs = make_obs(tmp_path / "on", role="engine")
