@@ -949,6 +949,23 @@ class _SingleRuntime:
         return res._replace(iters=jnp.int32(iters))
 
 
+def _chip_piece(chunks, dt):
+    """One chip's (spd, chunk, K) host piece of a column: a view of its
+    one slot's chunk, or a stack of its slots' chunks."""
+    if len(chunks) == 1:
+        return np.asarray(chunks[0], dt)[None]
+    return np.stack(chunks, dtype=dt, casting="unsafe")
+
+
+def _stack_bytes(chunks, spd, dt):
+    """Host bytes :func:`_chip_piece` copies for a column's ``chunks``:
+    all of them when a chip holds several slots, else the chunks that
+    ``np.asarray`` has to cast to ``dt``."""
+    return sum(np.size(c) * dt.itemsize for c in chunks
+               if spd > 1 or not (isinstance(c, np.ndarray)
+                                  and c.dtype == dt))
+
+
 class _ShardedRuntime:
     """Virtual-slot shard_map feeding: S slots over D devices (S % D == 0).
 
@@ -971,9 +988,17 @@ class _ShardedRuntime:
         self.fin_cols = self.cps
         self.scr = None   # HostScreen over slots*cps padded chunk slots
         self.tracer = NULL_TRACER   # phase-span tracer, driver-installed
-        spd = slots // mesh.devices.size
+        self.spd = spd = slots // mesh.devices.size
         self.st = _jit_steps_sharded(cfg, q, mesh, spd)
         self.slot_sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+        # Which slots each addressable chip holds of an (S, chunk, K)
+        # column, from the sharding's own device -> index map: a chip's
+        # piece is uploaded straight from its slots' chunks.
+        self.col_shape = (slots, source.chunk, source.k)
+        idx = self.slot_sh.addressable_devices_indices_map(self.col_shape)
+        self.chips = list(idx)
+        self.chip_slots = [range(*idx[d][0].indices(slots))
+                           for d in self.chips]
         self.budgets = jnp.asarray(source.budgets, cfg.dtype)
         self.keep = jax.device_put(self.st["keep_np"], self.slot_sh)
 
@@ -1007,13 +1032,19 @@ class _ShardedRuntime:
         with self.tracer.span("ingest.fetch", col=int(j)):
             ps, bs = self._fetch_cols(j, screen, dt)
         with self.tracer.span("ingest.h2d", col=int(j)):
-            # The host copy of the S slots' chunks into one column; the
-            # rest of ``ingest.h2d`` is the sharded upload alone.
-            with self.tracer.span("ingest.stack", col=int(j)):
-                pb = np.ascontiguousarray(np.stack(ps), dtype=dt)
-                bb = np.ascontiguousarray(np.stack(bs), dtype=dt)
-            return (jax.device_put(pb, self.slot_sh),
-                    jax.device_put(bb, self.slot_sh))
+            # The host assembly of each chip's piece: a view of its one
+            # slot's chunk, or a stack of its slots' chunks; the rest of
+            # ``ingest.h2d`` is the per-chip upload alone.
+            with self.tracer.span("ingest.stack", col=int(j),
+                                  bytes=_stack_bytes((*ps, *bs), self.spd,
+                                                     dt)):
+                pieces = [_chip_piece([cs[s] for s in sl], dt)
+                          for cs in (ps, bs) for sl in self.chip_slots]
+            arrs = jax.device_put(pieces, self.chips * 2)
+            n = len(self.chips)
+            return tuple(jax.make_array_from_single_device_arrays(
+                self.col_shape, self.slot_sh, arrs[i:i + n])
+                for i in (0, n))
 
     def _epoch_cols(self, step, state, extra, start=0, on_col=None,
                     indices=None, screen=False):
@@ -1208,7 +1239,9 @@ def solve_streaming_host(source: HostChunkSource,
     them: ``ckpt.save`` with ``ckpt.gather`` and ``ckpt.write`` per
     resume-state save, ``ingest.fetch`` / ``ingest.h2d`` and
     ``screen.skip``; on the sharded runtime also ``ingest.stack`` (the
-    host copy of a column's slot chunks, inside its ``ingest.h2d``) and
+    host assembly of each chip's piece of a column: a view of its one
+    slot's chunk, or a stack of its slots' chunks, with the ``bytes``
+    it copied; inside the column's ``ingest.h2d``) and
     ``slots.combine`` (each epoch's read-back and fixed-order fold of
     the slot partials). Tracing is *not* a ``SolverConfig`` field:
     it never enters the resume fingerprint, and because spans bracket
